@@ -35,6 +35,9 @@ def main(argv=None) -> int:
                     help="comma list of module names to run")
     args = ap.parse_args(argv)
 
+    from repro.platform import enable_compile_cache
+
+    enable_compile_cache()
     trials = args.trials or (200 if args.quick else 1000)
     steps = 16 if args.quick else 40
 

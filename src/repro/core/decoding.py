@@ -160,7 +160,9 @@ def algorithmic_weights(G: np.ndarray, mask: np.ndarray, iters: int,
     if A.shape[1] == 0 or iters <= 0:
         return w
     if nu is None:
-        nu = _spectral_norm_sq(A)
+        # an all-zero A (the alive workers hold no task) decodes to w = 0
+        # for any nu; 1 keeps 0/0 out, as the batched path does
+        nu = _spectral_norm_sq(A) or 1.0
     u = np.ones(k)
     x = np.zeros(A.shape[1])
     for _ in range(iters):
@@ -176,7 +178,7 @@ def algorithmic_error_curve(A: np.ndarray, iters: int, nu: Optional[float] = Non
     A = _as2d(A)
     k = A.shape[0]
     if nu is None:
-        nu = _spectral_norm_sq(A)
+        nu = _spectral_norm_sq(A) or 1.0      # all-zero A: see above
     u = np.ones(k)
     out = [float(u @ u)]
     for _ in range(iters):

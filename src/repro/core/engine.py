@@ -145,8 +145,8 @@ class DecodeEngine:
             return False
         idx, _ = self.code.ell()
         rmax = idx.shape[1]
-        # gather wins when the packed row is meaningfully narrower than
-        # the dense worker dimension
+        # the packed read wins when the packed row is meaningfully
+        # narrower than the dense worker dimension
         return self.sparse == "always" or 4 * rmax <= self.n
 
     # ------------------------------------------------------------------

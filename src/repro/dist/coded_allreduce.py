@@ -57,11 +57,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.4.35 keeps shard_map under jax.experimental
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover - newer jax moved it to the top level
-    from jax import shard_map  # type: ignore[attr-defined]
-
 from ..core.assignment import CodedAssignment, build_assignment
 from ..core.codes import GradientCode
 from ..core.engine import DecodeEngine
@@ -214,11 +209,11 @@ class CodedAllReduce:
         """shard_map manual over the worker axis only: any other mesh
         axes (data/model/FSDP) stay automatic, so GSPMD keeps sharding
         params and activations over them inside the worker-local body."""
-        kw = {"auto": self.auto_axes} if self.auto_axes else {}
-        out = shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                        out_specs=out_specs, check_rep=False, **kw)
-        # partial-auto shard_map only lowers under jit (jax 0.4.37's
-        # eager impl rejects auto axes), so multi-axis meshes force it
+        out = jax.shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                            out_specs=out_specs,
+                            axis_names={self.axis_name}, check_vma=False)
+        # partial-auto shard_map only lowers under jit, so multi-axis
+        # meshes force it
         return jax.jit(out) if self.auto_axes else out
 
     # ------------------------------------------------------------------

@@ -25,9 +25,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 __all__ = ["algorithmic_decode", "algorithmic_iterate"]
+
+# Mosaic contracts fp32 operands at reduced precision by default; decode
+# weights and gradient messages are fp32 quantities, so ask for fp32
+_FP32 = jax.lax.Precision.HIGHEST
 
 
 def _atu_kernel(g_ref, m_ref, u_ref, o_ref, acc_ref, *, nk: int):
@@ -41,6 +43,7 @@ def _atu_kernel(g_ref, m_ref, u_ref, o_ref, acc_ref, *, nk: int):
     u = u_ref[...]                               # [1, bk]
     acc_ref[...] += jax.lax.dot_general(
         u, g, (((1,), (0,)), ((), ())),
+        precision=_FP32,
         preferred_element_type=jnp.float32)      # [1, bn]
 
     @pl.when(i == nk - 1)
@@ -59,6 +62,7 @@ def _axpy_kernel(g_ref, t_ref, u_ref, o_ref, acc_ref, *, nn: int, inv_nu: float)
     t = t_ref[...]                               # [1, bn] (already masked)
     acc_ref[...] += jax.lax.dot_general(
         g, t, (((1,), (1,)), ((), ())),
+        precision=_FP32,
         preferred_element_type=jnp.float32)      # [bk, 1]
 
     @pl.when(j == nn - 1)
@@ -92,7 +96,7 @@ def algorithmic_iterate(G, mask, u, nu, *, bk=512, bn=512, interpret=False):
         out_specs=pl.BlockSpec((1, bn), lambda jj, ii: (0, jj)),
         out_shape=jax.ShapeDtypeStruct((1, nn * bn), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(g, m, up)
@@ -108,7 +112,7 @@ def algorithmic_iterate(G, mask, u, nu, *, bk=512, bn=512, interpret=False):
         out_specs=pl.BlockSpec((bk, 1), lambda ii, jj: (ii, 0)),
         out_shape=jax.ShapeDtypeStruct((nk * bk, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bk, 1), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(g, t, up)

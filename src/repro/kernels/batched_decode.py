@@ -9,8 +9,8 @@ decoded in one launch:
 
     batched_onestep_decode      V = diag(rho) * M G^T          [B, k]
     batched_onestep_decode_ell  same, via the row-ELL packing of G
-                                (reads B*k*rmax mask entries instead of
-                                streaming B*k*n dense zeros)
+                                (reads k*rmax packed entries of G from
+                                HBM instead of the dense k*n)
     batched_algorithmic_decode  U_t per mask, Lemma-12 iterates [B, k]
     batched_masked_gram         diag(m_b) Gram diag(m_b)       [B, n, n]
                                 (the normal-equations ensemble feeding
@@ -33,8 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 __all__ = [
     "batched_onestep_decode",
     "batched_onestep_decode_ell",
@@ -42,6 +40,10 @@ __all__ = [
     "batched_algorithmic_iterate",
     "batched_masked_gram",
 ]
+
+# Mosaic contracts fp32 operands at reduced precision by default; decode
+# weights and gradient messages are fp32 quantities, so ask for fp32
+_FP32 = jax.lax.Precision.HIGHEST
 
 
 def _pad2(x, r, c):
@@ -66,6 +68,7 @@ def _onestep_batch_kernel(m_ref, g_ref, r_ref, o_ref, acc_ref, *, nn: int):
     g = g_ref[...].astype(jnp.float32)           # [bk, bn]
     acc_ref[...] += jax.lax.dot_general(
         m, g, (((1,), (1,)), ((), ())),
+        precision=_FP32,
         preferred_element_type=jnp.float32)      # [bb, bk]
 
     @pl.when(j == nn - 1)
@@ -104,7 +107,7 @@ def batched_onestep_decode(
         out_specs=pl.BlockSpec((bb, bk), lambda b, i, j: (b, i)),
         out_shape=jax.ShapeDtypeStruct((nb * bb, nk * bk), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bb, bk), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(m, g, r)
@@ -112,18 +115,27 @@ def batched_onestep_decode(
 
 
 # --------------------------------------------------------------------------
-# ELL batched one-step: gather the masks at each row's support instead of
-# streaming the dense zero entries of G.
+# ELL batched one-step: read G as its row-ELL packing (k*rmax entries)
+# instead of streaming the dense zero entries of G from HBM.
 # --------------------------------------------------------------------------
 
 def _onestep_ell_kernel(m_ref, i_ref, v_ref, r_ref, o_ref):
     m = m_ref[...]                               # [bb, n]
-    idx = i_ref[...]                             # [bk, rmax] int32
-    val = v_ref[...].astype(jnp.float32)         # [bk, rmax]
-    bk, rmax = idx.shape
-    gathered = jnp.take(m, idx.reshape(-1), axis=1)        # [bb, bk*rmax]
-    gathered = gathered.reshape(m.shape[0], bk, rmax)
-    v = jnp.sum(gathered * val[None, :, :], axis=2)        # [bb, bk]
+    n, (rmax, bk) = m.shape[1], i_ref.shape
+    rows = jax.lax.broadcasted_iota(jnp.int32, (n, bk), 0)
+
+    # Mosaic has no in-kernel gather: unpack the [n, bk] block of G^T in
+    # VMEM, one ELL slot at a time (padding slots add val 0), and let
+    # the MXU contract it against the masks
+    def unpack(r, gt):
+        idx = i_ref[pl.ds(r, 1), :]              # [1, bk] int32
+        val = v_ref[pl.ds(r, 1), :]              # [1, bk]
+        return gt + jnp.where(rows == idx, val, 0.0)
+
+    gt = jax.lax.fori_loop(0, rmax, unpack, jnp.zeros((n, bk), jnp.float32))
+    v = jax.lax.dot_general(m, gt, (((1,), (0,)), ((), ())),
+                            precision=_FP32,
+                            preferred_element_type=jnp.float32)  # [bb, bk]
     o_ref[...] = v * r_ref[...]
 
 
@@ -140,16 +152,18 @@ def batched_onestep_decode_ell(
 ) -> jax.Array:
     """Sparse batched Algorithm 1 via the GradientCode.ell() packing.
 
-    Padding rows carry (idx 0, val 0), so they add exactly 0.  The mask
-    block spans the full worker dimension (n is at most a few thousand —
-    the paper's regime — so a [bb, n] tile fits VMEM comfortably).
+    Padding slots carry (idx 0, val 0), so they add exactly 0.  The mask
+    block and the unpacked [n, bk] block of G^T span the full worker
+    dimension (n is at most a few thousand — the paper's regime — so
+    both fit VMEM comfortably).
     """
     k, rmax = ell_idx.shape
     B, n = masks.shape
     bb, bk = min(bb, B), min(bk, k)
     nb, nk = math.ceil(B / bb), math.ceil(k / bk)
-    idx = _pad2(ell_idx.astype(jnp.int32), nk * bk, rmax)
-    val = _pad2(ell_val.astype(jnp.float32), nk * bk, rmax)
+    # slot-major [rmax, k]: each ELL slot is one lane-dense row
+    idx = _pad2(ell_idx.astype(jnp.int32).T, rmax, nk * bk)
+    val = _pad2(ell_val.astype(jnp.float32).T, rmax, nk * bk)
     m = _pad2(masks.astype(jnp.float32), nb * bb, n)
     r = _pad2(rhos.astype(jnp.float32)[:, None], nb * bb, 1)
 
@@ -158,13 +172,13 @@ def batched_onestep_decode_ell(
         grid=(nb, nk),
         in_specs=[
             pl.BlockSpec((bb, n), lambda b, i: (b, 0)),
-            pl.BlockSpec((bk, rmax), lambda b, i: (i, 0)),
-            pl.BlockSpec((bk, rmax), lambda b, i: (i, 0)),
+            pl.BlockSpec((rmax, bk), lambda b, i: (0, i)),
+            pl.BlockSpec((rmax, bk), lambda b, i: (0, i)),
             pl.BlockSpec((bb, 1), lambda b, i: (b, 0)),
         ],
         out_specs=pl.BlockSpec((bb, bk), lambda b, i: (b, i)),
         out_shape=jax.ShapeDtypeStruct((nb * bb, nk * bk), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(m, idx, val, r)
@@ -218,7 +232,7 @@ def batched_masked_gram(
         out_specs=pl.BlockSpec((bb, bi, bj), lambda b, i, j: (b, i, j)),
         out_shape=jax.ShapeDtypeStruct((nb * bb, ni * bi, nj * bj),
                                        jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(m, m, g)
@@ -241,6 +255,7 @@ def _batched_atu_kernel(u_ref, g_ref, m_ref, o_ref, acc_ref, *, nk: int):
     g = g_ref[...].astype(jnp.float32)           # [bk, bn]
     acc_ref[...] += jax.lax.dot_general(
         u, g, (((1,), (0,)), ((), ())),
+        precision=_FP32,
         preferred_element_type=jnp.float32)      # [bb, bn]
 
     @pl.when(i == nk - 1)
@@ -260,6 +275,7 @@ def _batched_axpy_kernel(t_ref, g_ref, u_ref, inv_ref, o_ref, acc_ref,
     g = g_ref[...].astype(jnp.float32)           # [bk, bn]
     acc_ref[...] += jax.lax.dot_general(
         t, g, (((1,), (1,)), ((), ())),
+        precision=_FP32,
         preferred_element_type=jnp.float32)      # [bb, bk]
 
     @pl.when(j == nn - 1)
@@ -292,7 +308,7 @@ def batched_algorithmic_iterate(G, masks, U, inv_nus, *, bb=128, bk=256,
         out_specs=pl.BlockSpec((bb, bn), lambda b, j, i: (b, j)),
         out_shape=jax.ShapeDtypeStruct((B, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bb, bn), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(U, G, masks)
@@ -309,7 +325,7 @@ def batched_algorithmic_iterate(G, masks, U, inv_nus, *, bb=128, bk=256,
         out_specs=pl.BlockSpec((bb, bk), lambda b, i, j: (b, i)),
         out_shape=jax.ShapeDtypeStruct((B, k), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bb, bk), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(T, G, U, inv_nus)

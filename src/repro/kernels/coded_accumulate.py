@@ -30,9 +30,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 __all__ = ["coded_accumulate", "coded_accumulate_batched"]
+
+# Mosaic contracts fp32 operands at reduced precision by default; decode
+# weights and gradient messages are fp32 quantities, so ask for fp32
+_FP32 = jax.lax.Precision.HIGHEST
 
 
 def _acc_kernel(w_ref, g_ref, o_ref):
@@ -40,6 +42,7 @@ def _acc_kernel(w_ref, g_ref, o_ref):
     g = g_ref[...].astype(jnp.float32)       # [k, bp]
     o_ref[...] = jax.lax.dot_general(
         w, g, (((1,), (0,)), ((), ())),
+        precision=_FP32,
         preferred_element_type=jnp.float32)  # [1, bp]
 
 
@@ -68,7 +71,7 @@ def coded_accumulate(
         ],
         out_specs=pl.BlockSpec((1, bp), lambda p: (0, p)),
         out_shape=jax.ShapeDtypeStruct((1, np_ * bp), jnp.float32),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(w, g)
@@ -86,6 +89,7 @@ def _acc_batch_kernel(w_ref, g_ref, o_ref, acc_ref, *, nk: int):
     g = g_ref[...].astype(jnp.float32)       # [bk, bp]
     acc_ref[...] += jax.lax.dot_general(
         w, g, (((1,), (0,)), ((), ())),
+        precision=_FP32,
         preferred_element_type=jnp.float32)  # [bb, bp]
 
     @pl.when(i == nk - 1)
@@ -132,7 +136,7 @@ def coded_accumulate_batched(
         out_specs=pl.BlockSpec((bb, bp), lambda b, p, i: (b, p)),
         out_shape=jax.ShapeDtypeStruct((nb * bb, np_ * bp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bb, bp), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(w, g)

@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 __all__ = ["flash_attention"]
 
 _NEG_INF = -1e30
@@ -159,7 +157,7 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),     # denominator l
             pltpu.VMEM((bq, dh), jnp.float32),    # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

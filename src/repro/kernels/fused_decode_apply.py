@@ -30,9 +30,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 __all__ = ["fused_decode_apply"]
+
+# Mosaic contracts fp32 operands at reduced precision by default; decode
+# weights and gradient messages are fp32 quantities, so ask for fp32
+_FP32 = jax.lax.Precision.HIGHEST
 
 
 def _fused_kernel(m_ref, g_ref, s_ref, o_ref, acc_ref, *, nl: int):
@@ -46,6 +48,7 @@ def _fused_kernel(m_ref, g_ref, s_ref, o_ref, acc_ref, *, nl: int):
     g = g_ref[...].astype(jnp.float32)       # [bl, bp] message tile
     acc_ref[...] += jax.lax.dot_general(
         m, g, (((1,), (0,)), ((), ())),
+        precision=_FP32,
         preferred_element_type=jnp.float32)  # [bb, bp]
 
     @pl.when(i == nl - 1)
@@ -89,7 +92,7 @@ def fused_decode_apply(
         out_specs=pl.BlockSpec((bb, bp), lambda b, p, i: (b, p)),
         out_shape=jax.ShapeDtypeStruct((nb * bb, np_ * bp), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bb, bp), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(m, g, s)

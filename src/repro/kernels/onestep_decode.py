@@ -20,9 +20,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 __all__ = ["onestep_decode"]
+
+# Mosaic contracts fp32 operands at reduced precision by default; decode
+# weights and gradient messages are fp32 quantities, so ask for fp32
+_FP32 = jax.lax.Precision.HIGHEST
 
 
 def _onestep_kernel(g_ref, m_ref, o_ref, acc_ref, *, nn: int, rho: float):
@@ -36,6 +38,7 @@ def _onestep_kernel(g_ref, m_ref, o_ref, acc_ref, *, nn: int, rho: float):
     m = m_ref[...]                           # [1, bn]
     acc_ref[...] += jax.lax.dot_general(
         g, m, (((1,), (1,)), ((), ())),
+        precision=_FP32,
         preferred_element_type=jnp.float32)  # [bk, 1]
 
     @pl.when(j == nn - 1)
@@ -76,7 +79,7 @@ def onestep_decode(
         out_specs=pl.BlockSpec((bk, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nk * bk, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bk, 1), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(g, m)

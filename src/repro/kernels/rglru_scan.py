@@ -25,34 +25,24 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 __all__ = ["rglru_scan"]
 
 
-def _rglru_kernel(u_ref, la_ref, h0_ref, h_ref, carry_ref, *,
-                  chunk: int, seq_len: int):
+def _rglru_kernel(u_ref, la_ref, h0_ref, h_ref, carry_ref, *, chunk: int):
     si = pl.program_id(2)
 
     @pl.when(si == 0)
     def _init():
         carry_ref[...] = h0_ref[0].astype(jnp.float32)    # [1, bd]
 
-    u = u_ref[0].astype(jnp.float32)        # [chunk, bd]
-    la = la_ref[0].astype(jnp.float32)      # [chunk, bd]
-    a = jnp.exp(la)
+    def step(t, h_prev):                    # h_prev [1, bd]
+        u_t = u_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
+        a_t = jnp.exp(la_ref[0, pl.ds(t, 1), :].astype(jnp.float32))
+        h_t = a_t * h_prev + u_t
+        h_ref[0, pl.ds(t, 1), :] = h_t.astype(h_ref.dtype)
+        return h_t
 
-    def step(t, carry):
-        h_prev, out = carry
-        h_t = a[t] * h_prev + u[t]
-        out = jax.lax.dynamic_update_index_in_dim(out, h_t, t, 0)
-        return h_t, out
-
-    h_last, out = jax.lax.fori_loop(
-        0, chunk, step,
-        (carry_ref[0], jnp.zeros((chunk, u.shape[1]), jnp.float32)))
-    carry_ref[0] = h_last
-    h_ref[0] = out.astype(h_ref.dtype)
+    carry_ref[...] = jax.lax.fori_loop(0, chunk, step, carry_ref[...])
 
 
 @functools.partial(
@@ -83,7 +73,7 @@ def rglru_scan(
     h0f = jnp.pad(h0.astype(jnp.float32), ((0, 0), (0, pd)))[:, None]
 
     out = pl.pallas_call(
-        functools.partial(_rglru_kernel, chunk=chunk, seq_len=S),
+        functools.partial(_rglru_kernel, chunk=chunk),
         grid=(B, nd, ns),
         in_specs=[
             pl.BlockSpec((1, chunk, bd), lambda b, d, s: (b, s, d)),
@@ -93,7 +83,7 @@ def rglru_scan(
         out_specs=pl.BlockSpec((1, chunk, bd), lambda b, d, s: (b, s, d)),
         out_shape=jax.ShapeDtypeStruct((B, ns * chunk, nd * bd), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(uf, laf, h0f)

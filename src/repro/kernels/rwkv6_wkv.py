@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._compat import CompilerParams
-
 __all__ = ["rwkv6_wkv"]
 
 
@@ -47,7 +45,15 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, s_out_ref,
     s = state_ref[...]                      # [dh, dh]
 
     logw = jnp.log(jnp.maximum(w, 1e-12))
-    ci = jnp.cumsum(logw, axis=0)           # inclusive  prod_{j<=t}
+    c = logw.shape[0]
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    # inclusive prefix sum over the chunk as a lower-triangular matmul
+    # (Mosaic has no cumsum)
+    ci = jax.lax.dot_general(
+        (row >= col).astype(jnp.float32), logw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)  # inclusive  prod_{j<=t}
     ce = ci - logw                          # exclusive  prod_{j<t}
 
     r_dec = r * jnp.exp(ce)
@@ -58,9 +64,6 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, s_out_ref,
     k_dec = k * jnp.exp(-ci)
     scores = jax.lax.dot_general(r_dec, k_dec, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-    c = scores.shape[0]
-    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
     scores = jnp.where(row > col, scores, 0.0)
     o = o + jax.lax.dot_general(scores, v, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
@@ -135,7 +138,7 @@ def rwkv6_wkv(
             jax.ShapeDtypeStruct((B * H, dh, dh), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dh, dh), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rt, kt, vt, wt, uu, s0f)

@@ -26,11 +26,11 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {need} devices, have {len(devs)} — call "
             f"repro.platform.host_devices(512) before jax initializes "
             f"(dryrun.py does this automatically)")
-    try:
-        return jax.make_mesh(shape, axes, devices=devs[:need])
-    except TypeError:  # older make_mesh without devices kwarg
-        return jax.sharding.Mesh(
-            np.asarray(devs[:need]).reshape(shape), axes)
+    # Auto axes: the models place activations with
+    # with_sharding_constraint, which Explicit axes reject
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devs[:need])
 
 
 def make_debug_mesh(data: int = 2, model: int = 2, pod: int = 0):

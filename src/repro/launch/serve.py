@@ -71,12 +71,17 @@ def main(argv=None) -> int:
 
     from repro.configs import get_config, list_archs
     from repro.models import build_model
+    from repro.platform import backend_info, enable_compile_cache
     from repro.serving import Request, ServingEngine
 
     if args.arch is None or args.arch not in list_archs():
         ap.error(f"--arch is required without --sim "
                  f"(choices: {', '.join(list_archs())})")
 
+    enable_compile_cache()
+    info = backend_info()
+    print(f"[serve] device: platform={info.platform} "
+          f"kind={info.device_kind!r} count={info.device_count}")
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(args.seed))

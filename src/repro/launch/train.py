@@ -6,8 +6,9 @@
 
 Selects any assigned architecture (``--arch``), builds the gradient code,
 wires the straggler model and fault plan, and runs the CodedTrainer.
-On this CPU box use ``--smoke`` (reduced config); the full configs are
-for the TPU meshes proven out by ``repro.launch.dryrun``.
+``--smoke`` selects a reduced same-family config that runs on a CPU
+host; the full configs run on TPU (``chip_smoke.py`` trains minicpm-2b
+at its published widths, cut in depth, on one v5e chip).
 """
 
 from __future__ import annotations
@@ -19,6 +20,7 @@ from repro.configs import get_config, list_archs
 from repro.core import registry
 from repro.models import build_model
 from repro.optim import OptConfig
+from repro.platform import backend_info, enable_compile_cache
 from repro.runtime import FaultInjector, make_straggler_model
 from repro.runtime.faults import FaultPlan
 from repro.training import CodedTrainConfig, CodedTrainer
@@ -84,6 +86,10 @@ def main(argv=None) -> int:
     ap.add_argument("--history-out", default=None)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
+    info = backend_info()
+    print(f"[train] device: platform={info.platform} "
+          f"kind={info.device_kind!r} count={info.device_count}")
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)
     print(f"[train] {cfg.name}: {model.param_count() / 1e6:.1f}M params, "

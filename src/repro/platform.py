@@ -31,6 +31,8 @@ Entry points:
     backend_info()               live (platform, devices, hardware
                                  spec); backend_key() is the stable
                                  string the bench baselines key on
+    enable_compile_cache()       JAX's persistent compile cache for the
+                                 mains (never called on import)
     HARDWARE / resolve_hardware  per-backend peak FLOPs / HBM / link
                                  bandwidth presets (launch/roofline.py
                                  reads these instead of hardcoding
@@ -45,14 +47,14 @@ from __future__ import annotations
 import dataclasses
 import os
 import sys
-import warnings
+from pathlib import Path
 from typing import Dict, Optional, Union
 
 __all__ = [
     "HardwareSpec", "HARDWARE", "PRESETS", "BackendInfo",
     "configure", "host_devices", "configure_from_env", "subprocess_env",
     "backend_info", "backend_key", "runtime_platform", "resolve_hardware",
-    "jax_is_initialized",
+    "jax_is_initialized", "enable_compile_cache",
 ]
 
 
@@ -107,12 +109,20 @@ _DEVICE_KIND_MAP = (
 
 
 def resolve_hardware(hw: Union[None, str, HardwareSpec]) -> HardwareSpec:
-    """HardwareSpec from a spec, a HARDWARE key, or a platform name."""
+    """HardwareSpec from a spec, a HARDWARE key, or a platform name.
+
+    ``None`` means the live device, which jax must already have
+    initialized; an unknown device raises (see :func:`backend_info`).
+    """
     if isinstance(hw, HardwareSpec):
         return hw
     if hw is None:
-        return HARDWARE[_PLATFORM_DEFAULT_HW.get(
-            runtime_platform() or "cpu", "cpu")]
+        if not jax_is_initialized():
+            raise RuntimeError(
+                "resolve_hardware(None) names the live device, but jax has "
+                "not initialized a backend; pass a HARDWARE key "
+                f"{sorted(HARDWARE)} or a platform name")
+        return backend_info().hardware
     if hw in HARDWARE:
         return HARDWARE[hw]
     if hw in _PLATFORM_DEFAULT_HW:
@@ -342,15 +352,22 @@ def _key_for(platform: str, device_kind: str) -> str:
 
 
 def backend_info() -> BackendInfo:
-    """Live world report.  Initializes jax (device query) if needed."""
+    """Live world report.  Initializes jax (device query) if needed.
+
+    Raises KeyError for a device with no HARDWARE preset: its peaks are
+    unknown, and guessing another chip's would mislabel every number.
+    """
     import jax
 
     platform = jax.default_backend()
     devices = jax.devices()
     kind = getattr(devices[0], "device_kind", platform)
     key = _key_for(platform, kind)
-    hw = HARDWARE.get(key) or HARDWARE[_PLATFORM_DEFAULT_HW.get(
-        platform, "cpu")]
+    hw = HARDWARE.get(key)
+    if hw is None:
+        raise KeyError(f"no hardware preset for {platform} device_kind "
+                       f"{kind!r} (key {key!r}); add it to "
+                       f"repro.platform.HARDWARE and _DEVICE_KIND_MAP")
     return BackendInfo(platform=platform, device_count=len(devices),
                        device_kind=kind, key=key, hardware=hw)
 
@@ -371,3 +388,29 @@ def backend_key(initialize: bool = False) -> str:
     if plat == "cpu":
         return "cpu"
     return _PLATFORM_DEFAULT_HW.get(plat, plat)
+
+
+# --------------------------------------------------------------------------
+# persistent compilation cache
+# --------------------------------------------------------------------------
+
+_CHECKOUT = Path(__file__).resolve().parents[2]
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process.
+
+    Called by the mains (launchers, benchmark runner, chip smoke), never
+    on import.  ``JAX_COMPILATION_CACHE_DIR`` wins when set (JAX reads
+    it itself, and nothing else is set); otherwise the cache lives at
+    ``<checkout>/.jax_cache``, a fixed path, so the next process of this
+    checkout finds it.  Returns the directory.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    path = str(_CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

@@ -18,7 +18,7 @@ from repro.core import registry
 from repro.core import theory as T
 from repro.core.certify import adversarial_err1_bound, certify
 
-EXAMPLES = settings(max_examples=40)
+EXAMPLES = settings(max_examples=40, deadline=None)
 
 
 # --------------------------------------------------------------------------

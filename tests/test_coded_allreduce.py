@@ -686,12 +686,15 @@ def test_ragged_workers_metrics_match_fused_8_devices():
 # (Re-pinned when code builds moved to the counter-derived rng stream
 # default_rng([seed, 0xC0DE, builds]) for checkpoint-exact rebuilds:
 # frc's column permutation drew differently — permutation-invariant
-# statistically, verified against the fp64 differentials.)
+# statistically, verified against the fp64 differentials.  Re-pinned
+# again for jax 0.9, whose default PRNG is partitionable threefry: the
+# old curve still comes back under JAX_THREEFRY_PARTITIONABLE=0, so the
+# parameter init draws moved, not the coded path.)
 GOLDEN_DIST_MEAN_CE = [
-    6.23709774017334, 6.216646194458008, 6.194518566131592,
-    6.189853668212891, 6.147739410400391, 6.091350078582764,
-    6.030529022216797, 6.0014448165893555, 5.978209495544434,
-    5.885657787322998,
+    6.214293003082275, 6.22582483291626, 6.179835319519043,
+    6.14699125289917, 6.156777858734131, 6.114492416381836,
+    6.03016471862793, 6.012834548950195, 5.9240803718566895,
+    5.907427787780762,
 ]
 GOLDEN_DIST_SIM_TIME = 14.617005584431038
 

@@ -63,6 +63,18 @@ def test_property_batched_matches_scalar(k, s, scheme, seed):
                         atol=1e-8)
 
 
+def test_algorithmic_all_zero_support_decodes_to_zero():
+    """Alive workers that hold no task: the scalar decoders give w = 0
+    and err = k, as the batched path does (no 0/0 from nu = 0)."""
+    G = np.zeros((4, 6))
+    G[:, :2] = 1.0
+    m = np.array([False, False, True, True, True, True])
+    w = D.algorithmic_weights(G, m, iters=3)
+    assert_allclose(w, np.zeros(6))
+    assert_allclose(w, D.algorithmic_weights_batch(G, m[None], 3)[0])
+    assert_allclose(D.algorithmic_error_curve(G[:, m], 3), np.full(4, 4.0))
+
+
 def test_batched_optimal_error_matches_lstsq():
     code = _code("bgc", 48, 5, 3)
     masks = _masks(48, 12, 4)

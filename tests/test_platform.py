@@ -211,6 +211,50 @@ def test_resolve_hardware_and_device_kind_mapping():
     assert platform._key_for("cpu", "whatever") == "cpu"
 
 
+def test_resolve_hardware_none_needs_a_live_backend(monkeypatch):
+    # no live backend: naming "the live device" is an error, not a guess
+    monkeypatch.setattr(platform, "jax_is_initialized", lambda: False)
+    with pytest.raises(RuntimeError, match="not initialized"):
+        platform.resolve_hardware(None)
+    # live backend: the live device's own preset
+    monkeypatch.setattr(platform, "jax_is_initialized", lambda: True)
+    assert platform.resolve_hardware(None) is platform.backend_info().hardware
+
+
+@pytest.mark.parametrize("kind, key", [("TPU v5 lite", "tpu-v5e"),
+                                       ("TPU v4", "tpu-v4"),
+                                       ("TPU v9 ultra", None)])
+def test_backend_info_unknown_tpu_kind_raises(monkeypatch, kind, key):
+    import types
+
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(jax, "devices",
+                        lambda: [types.SimpleNamespace(device_kind=kind)])
+    if key is None:
+        with pytest.raises(KeyError, match="no hardware preset"):
+            platform.backend_info()
+    else:
+        info = platform.backend_info()
+        assert info.key == key and info.hardware is platform.HARDWARE[key]
+
+
+def test_compile_cache_dir_respects_env(monkeypatch):
+    import jax
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: updates.append(a))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert platform.enable_compile_cache() == "/elsewhere"
+    assert updates == []          # the env var is JAX's own: nothing set
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = str(REPO / ".jax_cache")
+    assert platform.enable_compile_cache() == path
+    assert updates == [("jax_compilation_cache_dir", path)]
+
+
 def test_roofline_reads_hardware_presets():
     from repro.launch import roofline
 
