@@ -1,0 +1,9 @@
+"""Put the checkout's root on sys.path, so that the benchmark's own
+package (``bench``) imports as it does under bench/run.py."""
+
+import sys
+from pathlib import Path
+
+ROOT = Path(__file__).resolve().parents[2]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
