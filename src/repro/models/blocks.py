@@ -13,6 +13,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import tracing
 from ..dist import constrain
 from .config import ArchConfig
 from .layers import attention, mlp, norm, rope
@@ -147,6 +148,25 @@ def attn_block_apply(
     ``kpos`` stays < 0 — the same "empty" convention the ring cache
     already uses — so later decode steps never attend to them.
     """
+    with jax.named_scope(tracing.ATTENTION):
+        x, new_cache = _attn_residual(params, x, cfg, positions, causal,
+                                      window, cache, seq_mask)
+    with jax.named_scope(tracing.MLP):
+        h2 = norm(x, params["ln2"], cfg.norm, io=cfg.norm_io)
+        aux = jnp.zeros((), jnp.float32)
+        if use_moe:
+            ff, aux = moe_lib.moe_apply(params["moe"], h2, cfg)
+        else:
+            ff = mlp(h2, params["mlp"], cfg.act)
+        x = x + ff
+    return x, new_cache, aux
+
+
+def _attn_residual(params: dict, x: jax.Array, cfg: ArchConfig,
+                   positions: jax.Array, causal: bool, window: int,
+                   cache: Optional[dict], seq_mask: Optional[jax.Array]
+                   ) -> Tuple[jax.Array, Optional[dict]]:
+    """x + attention(ln1(x)) and the updated cache (attn_block_apply)."""
     B, S, _ = x.shape
     ragged = positions.ndim == 2
     h = norm(x, params["ln1"], cfg.norm, io=cfg.norm_io)
@@ -226,14 +246,7 @@ def attn_block_apply(
 
     out = out.reshape(B, S, cfg.q_dim)
     x = x + jnp.einsum("bse,ed->bsd", out, params["attn"]["wo"])
-
-    h2 = norm(x, params["ln2"], cfg.norm, io=cfg.norm_io)
-    aux = jnp.zeros((), jnp.float32)
-    if use_moe:
-        ff, aux = moe_lib.moe_apply(params["moe"], h2, cfg)
-    else:
-        ff = mlp(h2, params["mlp"], cfg.act)
-    return x + ff, new_cache, aux
+    return x, new_cache
 
 
 def cross_block_apply(

@@ -13,6 +13,7 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import tracing
 from ..dist import constrain
 from .config import ArchConfig
 from .layers import cross_entropy, norm
@@ -263,10 +264,11 @@ def _run_blocks(params, cfg: ArchConfig, x, positions, caches=None,
 
 
 def _logits(params, cfg: ArchConfig, x: jax.Array) -> jax.Array:
-    x = norm(x, params["final_norm"], cfg.norm, io=cfg.norm_io)
-    head = params["embed"].T if cfg.tie_embeddings else params["head"]
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
-    return constrain(logits, "batch", "seq", "vocab")
+    with jax.named_scope(tracing.HEAD_CE):
+        x = norm(x, params["final_norm"], cfg.norm, io=cfg.norm_io)
+        head = params["embed"].T if cfg.tie_embeddings else params["head"]
+        logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
+        return constrain(logits, "batch", "seq", "vocab")
 
 
 def lm_forward(params, cfg: ArchConfig, batch: dict) -> Tuple[jax.Array, jax.Array]:
@@ -289,20 +291,21 @@ def _chunked_ce(params, cfg: ArchConfig, x: jax.Array, labels: jax.Array
     logits are checkpointed, recomputed in the backward pass).  Python
     loop, not lax.map — the dry-run's cost accounting must see every
     chunk (a while body is costed once).  Sec-Perf, command-r."""
-    head = (params["embed"].T if cfg.tie_embeddings
-            else params["head"]).astype(x.dtype)
+    with jax.named_scope(tracing.HEAD_CE):
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["head"]).astype(x.dtype)
 
-    def chunk_ce(xc, lc):
-        xc = norm(xc, params["final_norm"], cfg.norm, io=cfg.norm_io)
-        logits = jnp.einsum("bsd,dv->bsv", xc, head)
-        return cross_entropy(logits, lc, cfg.vocab)
+        def chunk_ce(xc, lc):
+            xc = norm(xc, params["final_norm"], cfg.norm, io=cfg.norm_io)
+            logits = jnp.einsum("bsd,dv->bsv", xc, head)
+            return cross_entropy(logits, lc, cfg.vocab)
 
-    chunk_ce = jax.checkpoint(chunk_ce)
-    c = cfg.loss_chunk
-    Sl = labels.shape[1]
-    outs = [chunk_ce(x[:, i: i + c], labels[:, i: i + c])
-            for i in range(0, Sl, c)]
-    return jnp.concatenate(outs, axis=1)                     # [B, Sl] fp32
+        chunk_ce = jax.checkpoint(chunk_ce)
+        c = cfg.loss_chunk
+        Sl = labels.shape[1]
+        outs = [chunk_ce(x[:, i: i + c], labels[:, i: i + c])
+                for i in range(0, Sl, c)]
+        return jnp.concatenate(outs, axis=1)                 # [B, Sl] fp32
 
 
 def lm_loss(params, cfg: ArchConfig, batch: dict) -> Tuple[jax.Array, dict]:
@@ -326,7 +329,8 @@ def lm_loss(params, cfg: ArchConfig, batch: dict) -> Tuple[jax.Array, dict]:
     else:
         logits, aux = lm_forward(params, cfg, batch)
         logits = logits[:, -Sl:]  # vlm: loss only on the text suffix
-        ce = cross_entropy(logits, labels, cfg.vocab)  # [B, Sl] fp32
+        with jax.named_scope(tracing.HEAD_CE):
+            ce = cross_entropy(logits, labels, cfg.vocab)  # [B, Sl] fp32
     mask = batch.get("loss_mask")
     if mask is None:
         mask = jnp.ones_like(ce)

@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import tracing
 from ..dist.sharding import logical_to_pspec
 
 __all__ = ["OptConfig", "init_opt_state", "adamw_update",
@@ -56,40 +57,41 @@ def global_norm(tree) -> jax.Array:
 def adamw_update(params, grads, state, cfg: OptConfig, lr: jax.Array
                  ) -> Tuple[Any, dict, dict]:
     """One AdamW step.  Returns (new_params, new_state, metrics)."""
-    step = state["step"] + 1
-    gnorm = global_norm(grads)
-    scale = jnp.minimum(1.0, cfg.clip_norm / jnp.maximum(gnorm, 1e-9)) \
-        if cfg.clip_norm > 0 else 1.0
+    with jax.named_scope(tracing.OPTIMIZER):
+        step = state["step"] + 1
+        gnorm = global_norm(grads)
+        scale = jnp.minimum(1.0, cfg.clip_norm / jnp.maximum(gnorm, 1e-9)) \
+            if cfg.clip_norm > 0 else 1.0
 
-    if cfg.compress == "int8":
-        from .compress import fake_quantize_int8
-        grads = jax.tree_util.tree_map(fake_quantize_int8, grads)
+        if cfg.compress == "int8":
+            from .compress import fake_quantize_int8
+            grads = jax.tree_util.tree_map(fake_quantize_int8, grads)
 
-    b1, b2 = cfg.b1, cfg.b2
-    bc1 = 1 - b1 ** step.astype(jnp.float32)
-    bc2 = 1 - b2 ** step.astype(jnp.float32)
+        b1, b2 = cfg.b1, cfg.b2
+        bc1 = 1 - b1 ** step.astype(jnp.float32)
+        bc2 = 1 - b2 ** step.astype(jnp.float32)
 
-    def upd(p, g, mu, nu):
-        g = g.astype(jnp.float32) * scale
-        mu = b1 * mu + (1 - b1) * g
-        nu = b2 * nu + (1 - b2) * (g * g)
-        mhat = mu / bc1
-        vhat = nu / bc2
-        delta = mhat / (jnp.sqrt(vhat) + cfg.eps)
-        if cfg.weight_decay > 0 and p.ndim >= 2:  # no decay on norms/biases
-            delta = delta + cfg.weight_decay * p.astype(jnp.float32)
-        return (p.astype(jnp.float32) - lr * delta).astype(p.dtype), mu, nu
+        def upd(p, g, mu, nu):
+            g = g.astype(jnp.float32) * scale
+            mu = b1 * mu + (1 - b1) * g
+            nu = b2 * nu + (1 - b2) * (g * g)
+            mhat = mu / bc1
+            vhat = nu / bc2
+            delta = mhat / (jnp.sqrt(vhat) + cfg.eps)
+            if cfg.weight_decay > 0 and p.ndim >= 2:  # no decay on norms/biases
+                delta = delta + cfg.weight_decay * p.astype(jnp.float32)
+            return (p.astype(jnp.float32) - lr * delta).astype(p.dtype), mu, nu
 
-    flat_p, tdef = jax.tree_util.tree_flatten(params)
-    flat_g = jax.tree_util.tree_leaves(grads)
-    flat_mu = jax.tree_util.tree_leaves(state["mu"])
-    flat_nu = jax.tree_util.tree_leaves(state["nu"])
-    out = [upd(p, g, m, n) for p, g, m, n in zip(flat_p, flat_g, flat_mu, flat_nu)]
-    new_p = jax.tree_util.tree_unflatten(tdef, [o[0] for o in out])
-    new_mu = jax.tree_util.tree_unflatten(tdef, [o[1] for o in out])
-    new_nu = jax.tree_util.tree_unflatten(tdef, [o[2] for o in out])
-    metrics = {"grad_norm": gnorm, "lr": lr}
-    return new_p, {"mu": new_mu, "nu": new_nu, "step": step}, metrics
+        flat_p, tdef = jax.tree_util.tree_flatten(params)
+        flat_g = jax.tree_util.tree_leaves(grads)
+        flat_mu = jax.tree_util.tree_leaves(state["mu"])
+        flat_nu = jax.tree_util.tree_leaves(state["nu"])
+        out = [upd(p, g, m, n) for p, g, m, n in zip(flat_p, flat_g, flat_mu, flat_nu)]
+        new_p = jax.tree_util.tree_unflatten(tdef, [o[0] for o in out])
+        new_mu = jax.tree_util.tree_unflatten(tdef, [o[1] for o in out])
+        new_nu = jax.tree_util.tree_unflatten(tdef, [o[2] for o in out])
+        metrics = {"grad_norm": gnorm, "lr": lr}
+        return new_p, {"mu": new_mu, "nu": new_nu, "step": step}, metrics
 
 
 def opt_state_shardings(param_axes, abstract_params, mesh: Mesh,

@@ -77,6 +77,7 @@ from ..dist import use_mesh
 from ..models import Model
 from ..optim import OptConfig, adamw_update, init_opt_state, make_schedule
 from ..runtime import FaultInjector, StragglerModel, NoStragglers
+from .. import tracing
 
 __all__ = ["CodedTrainConfig", "CodedTrainer", "explicit_master_decode_grads"]
 
@@ -473,6 +474,11 @@ class CodedTrainer:
             self._churn_cursor += 1
         if not fired:
             return state, step, False
+        with tracing.span(tracing.RECODE):
+            state, step = self._apply_churn(fired, step, state, ckpt)
+        return state, step, True
+
+    def _apply_churn(self, fired, step: int, state, ckpt):
         live = self._live_ids
         for ev in fired:
             live = self.churn.apply_event(live, ev)
@@ -497,7 +503,7 @@ class CodedTrainer:
             self.churn_log[-1]["restart_to"] = step
         self._build_code(len(self._live_ids))
         self._step_fn = self._make_step_fn()
-        return state, step, True
+        return state, step
 
     # ------------- main loop -------------
     def run(self, state=None, start_step: int = 0,
@@ -529,38 +535,43 @@ class CodedTrainer:
                 # --- hard faults -> elastic re-code ---
                 plan = self.faults.check(step)
                 if plan is not None:
-                    alive = self.faults.alive_count(n0)
-                    self._build_code(max(alive, 2))
-                    # step_fn closures capture partition-derived scalars
-                    # (ce_fix, D) — rebuild with the new code
-                    self._step_fn = self._make_step_fn()
+                    with tracing.span(tracing.RECODE):
+                        alive = self.faults.alive_count(n0)
+                        self._build_code(max(alive, 2))
+                        # step_fn closures capture partition-derived
+                        # scalars (ce_fix, D) — rebuild with the new code
+                        self._step_fn = self._make_step_fn()
 
                 # --- controller decision -> adaptive re-code ---
                 if self.controller is not None:
                     action = self.controller.decide(step)
                     if action is not None:
-                        self._apply_action(action)
+                        with tracing.span(tracing.RECODE):
+                            self._apply_action(action)
 
                 # --- straggler mask -> decode weights -> coded batch ---
-                mask, step_time = self._mask_and_time(step, self.assignment.n)
-                deferred = None
-                if t.staleness > 0:
-                    # pipelined: apply weights decoded `staleness` steps
-                    # ago, re-masked by TODAY's stragglers (their
-                    # messages never arrived); today's decode is issued
-                    # after the jitted step dispatch so it overlaps the
-                    # backprop (docs/architecture.md §10)
-                    if self._pending_w is None:   # warm start / post-flush
-                        ones = np.ones(self.assignment.n, dtype=bool)
-                        self._pending_w = [self.decode_weights_for(ones)
-                                           ] * t.staleness
-                    w = self._pending_w.pop(0) * mask
-                    deferred = mask
-                elif self._trace_weights is not None:
-                    w = self._trace_weights[step % self._trace_weights.shape[0]]
-                else:
-                    w = self.decode_weights_for(mask)
-                self.weight_log.append(np.array(w))
+                with tracing.span(tracing.DECODE):
+                    mask, step_time = self._mask_and_time(step,
+                                                          self.assignment.n)
+                    deferred = None
+                    if t.staleness > 0:
+                        # pipelined: apply weights decoded `staleness`
+                        # steps ago, re-masked by TODAY's stragglers
+                        # (their messages never arrived); today's decode
+                        # is issued after the jitted step dispatch so it
+                        # overlaps the backprop (docs/architecture.md §10)
+                        if self._pending_w is None:  # warm start / flush
+                            ones = np.ones(self.assignment.n, dtype=bool)
+                            self._pending_w = [self.decode_weights_for(ones)
+                                               ] * t.staleness
+                        w = self._pending_w.pop(0) * mask
+                        deferred = mask
+                    elif self._trace_weights is not None:
+                        w = self._trace_weights[
+                            step % self._trace_weights.shape[0]]
+                    else:
+                        w = self.decode_weights_for(mask)
+                    self.weight_log.append(np.array(w))
 
                 if self.controller is not None:
                     # realized decode error of the weights in effect —
@@ -576,54 +587,44 @@ class CodedTrainer:
                     self.controller.observe(step, mask, latencies=lat,
                                             decode_err=derr)
                 if self.allreduce is not None:
-                    batch_np = self.pipeline.device_batch_for_step(
-                        step, w, self.allreduce.partition)
-                    batch = self.allreduce.shard_batch(batch_np)
+                    with tracing.span(tracing.BATCH):
+                        batch_np = self.pipeline.device_batch_for_step(
+                            step, w, self.allreduce.partition)
+                    with tracing.span(tracing.H2D):
+                        batch = self.allreduce.shard_batch(batch_np)
                 else:
-                    batch_np = self.pipeline.batch_for_step(step, w)
-                    batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
+                    with tracing.span(tracing.BATCH):
+                        batch_np = self.pipeline.batch_for_step(step, w)
+                    with tracing.span(tracing.H2D):
+                        batch = {k: jnp.asarray(v)
+                                 for k, v in batch_np.items()}
 
-                state["params"], state["opt"], metrics = self._step_fn(
-                    state["params"], state["opt"], batch)
+                with tracing.span(tracing.DISPATCH):
+                    state["params"], state["opt"], metrics = self._step_fn(
+                        state["params"], state["opt"], batch)
 
                 if deferred is not None:
                     # decode of step t's own mask, issued while the step
                     # above executes asynchronously — consumed at t+st.
                     # The trace-schedule path reuses its precomputed row
                     # (still ONE decode_batch per trace)
-                    if self._trace_weights is not None:
-                        S = self._trace_weights.shape[0]
-                        self._pending_w.append(self._trace_weights[step % S])
-                    else:
-                        self._pending_w.append(
-                            self.decode_weights_for(deferred))
+                    with tracing.span(tracing.DECODE):
+                        if self._trace_weights is not None:
+                            S = self._trace_weights.shape[0]
+                            self._pending_w.append(
+                                self._trace_weights[step % S])
+                        else:
+                            self._pending_w.append(
+                                self.decode_weights_for(deferred))
 
                 if step % max(t.log_every, 1) == 0 or step == end - 1:
-                    # read the LIVE config: controller actions may have
-                    # replaced self.tcfg since the loop started
-                    live = self.tcfg
-                    rec = {"step": step,
-                           "loss": float(metrics["loss"]),
-                           "mean_ce": float(metrics["mean_ce"]),
-                           "grad_norm": float(metrics["grad_norm"]),
-                           "stragglers": int((~mask).sum()),
-                           "decode_err": float(
-                               DEC.err1(self.code.G[:, mask],
-                                        DEC.default_rho(self.code.k,
-                                                        int(mask.sum()),
-                                                        self.code.s))
-                               if live.decoder == "onestep" else
-                               DEC.err(self.code.G[:, mask])) / self.code.k,
-                           "n_workers": self.assignment.n,
-                           "s": self.code.s,
-                           "decoder": live.decoder}
-                    if step_time is not None:
-                        rec["step_time"] = float(step_time)
-                        rec["sim_time"] = float(self.sim_time)
-                    self.history.append(rec)
+                    with tracing.span(tracing.LOG):
+                        self._log(step, metrics, mask, step_time)
 
                 if ckpt and t.ckpt_every and (step + 1) % t.ckpt_every == 0:
-                    ckpt.save(step + 1, state, self._ckpt_metadata(step + 1))
+                    with tracing.span(tracing.CKPT):
+                        ckpt.save(step + 1, state,
+                                  self._ckpt_metadata(step + 1))
 
                 step += 1
 
@@ -631,6 +632,34 @@ class CodedTrainer:
             ckpt.close()
         return {"state": state, "history": self.history,
                 "final_step": end}
+
+    def _log(self, step: int, metrics: dict, mask: np.ndarray,
+             step_time) -> None:
+        """Append the step's history record: one device read of its
+        scalars."""
+        # read the LIVE config: controller actions may have replaced
+        # self.tcfg since the loop started
+        live = self.tcfg
+        loss, mean_ce, grad_norm = jax.device_get(
+            (metrics["loss"], metrics["mean_ce"], metrics["grad_norm"]))
+        rec = {"step": step,
+               "loss": float(loss),
+               "mean_ce": float(mean_ce),
+               "grad_norm": float(grad_norm),
+               "stragglers": int((~mask).sum()),
+               "decode_err": float(
+                   DEC.err1(self.code.G[:, mask],
+                            DEC.default_rho(self.code.k, int(mask.sum()),
+                                            self.code.s))
+                   if live.decoder == "onestep" else
+                   DEC.err(self.code.G[:, mask])) / self.code.k,
+               "n_workers": self.assignment.n,
+               "s": self.code.s,
+               "decoder": live.decoder}
+        if step_time is not None:
+            rec["step_time"] = float(step_time)
+            rec["sim_time"] = float(self.sim_time)
+        self.history.append(rec)
 
 
 def explicit_master_decode_grads(model: Model, params, trainer: CodedTrainer,
