@@ -58,12 +58,22 @@ def _task_tokens(seed: int, step: int, task: int, rows: int, seq: int,
 
 
 class CodedDataPipeline:
-    """Produces physical batches laid out [worker, slot, row] -> flat B."""
+    """Produces physical batches laid out [worker, slot, row] -> flat B,
+    and their fold to the held examples (``unique_batch_for_step``)."""
 
     def __init__(self, assignment: CodedAssignment, cfg: PipelineConfig):
         self.asg = assignment
         self.cfg = cfg
         self._lane_mask_cache: Dict[tuple, np.ndarray] = {}
+        # fold tables (unique_batch_for_step): the physical rows that are
+        # not padding, each one's held example (task order), and the first
+        # replica of each held example.  Per assignment, so every re-code
+        # (reshard_for) rebuilds them.
+        uniq = assignment.unique_row_of_slot(cfg.rows_per_slot)
+        self._fold_rows = np.flatnonzero(uniq >= 0)
+        _, first, self._fold_dst = np.unique(
+            uniq[self._fold_rows], return_index=True, return_inverse=True)
+        self._fold_src = self._fold_rows[first]
 
     def reshard_for(self, assignment: CodedAssignment) -> "CodedDataPipeline":
         """Rebind the stream to a new assignment (elastic re-code / churn).
@@ -113,6 +123,26 @@ class CodedDataPipeline:
 
         weights = self.asg.row_weights(decode_w, T)
         return {"tokens": tokens, "labels": labels, "loss_weight": weights}
+
+    def unique_batch_for_step(self, step: int, decode_w: np.ndarray
+                              ) -> Dict[str, np.ndarray]:
+        """The physical batch folded to one row per held example.
+
+        Rows are (held task, row-in-slot) in task order; tasks no worker
+        holds and padding slots are absent.  Tokens and labels come from
+        a task's first replica (replicas are equal by construction), the
+        loss weight is the float64 sum of its replicas' row weights,
+        sum_j w_j G[i,j] / (k*T) — so the weighted loss and its gradient
+        are the physical batch's (docs/architecture.md §2.1), from each
+        example computed once.  Folds what ``batch_for_step`` returns.
+        """
+        b = self.batch_for_step(step, decode_w)
+        src = self._fold_src
+        w = np.bincount(self._fold_dst,
+                        weights=b["loss_weight"][self._fold_rows],
+                        minlength=src.size)
+        return {"tokens": b["tokens"][src], "labels": b["labels"][src],
+                "loss_weight": w}
 
     def device_batch_for_step(self, step: int, decode_w: np.ndarray,
                               partition) -> Dict[str, np.ndarray]:

@@ -8,7 +8,9 @@ Per step:
   3. the pipeline materializes the physical batch with per-row loss
      weights  w_j * G[i,j] / (k*T)  — the decode-as-loss-reweighting
      identity (docs/architecture.md §2.1), so XLA's ordinary gradient all-reduce IS
-     the coded aggregation;
+     the coded aggregation.  On one device the batch is folded to each
+     held example once, weighted by its replicas' summed weight (same
+     loss and gradient; history ``rows`` counts what the step computed);
   4. one jitted train_step (grad + AdamW) under the active mesh.
 
 Elasticity: on hard faults the worker set shrinks, the code is rebuilt
@@ -200,6 +202,12 @@ class CodedTrainer:
                         f"actuator); got {type(self.sync_policy).__name__}")
         elif sync_policy is not None:
             raise ValueError("sync_policy requires trace= or churn=")
+        # fused on one device: a program on one chip runs all of its rows
+        # or none, so replicas buy no straggler tolerance there — fold
+        # them (and padding) away; over several devices the batch's
+        # sharding maps workers to devices, so the physical layout stays
+        self._fold = tcfg.dist_mode == "fused" and (
+            mesh is None or np.size(mesh.devices) == 1)
         self._build_code(tcfg.n_workers)
         self._step_fn = self._make_step_fn()
         self.history: list = []
@@ -343,7 +351,8 @@ class CodedTrainer:
             D = part.n_devices
             # padding-lane rows are masked out of the per-row CE (see
             # device_batch_for_step) but still counted by row.mean();
-            # padded_n/n undoes the dilution so mean_ce matches fused
+            # padded_n/n undoes the dilution: mean_ce is the mean over
+            # the n workers' rows, the physical layout
             ce_fix = part.padded_n / part.n
 
             def step_fn(params, opt_state, batch):
@@ -594,7 +603,10 @@ class CodedTrainer:
                         batch = self.allreduce.shard_batch(batch_np)
                 else:
                     with tracing.span(tracing.BATCH):
-                        batch_np = self.pipeline.batch_for_step(step, w)
+                        batch_np = (
+                            self.pipeline.unique_batch_for_step(step, w)
+                            if self._fold else
+                            self.pipeline.batch_for_step(step, w))
                     with tracing.span(tracing.H2D):
                         batch = {k: jnp.asarray(v)
                                  for k, v in batch_np.items()}
@@ -619,7 +631,9 @@ class CodedTrainer:
 
                 if step % max(t.log_every, 1) == 0 or step == end - 1:
                     with tracing.span(tracing.LOG):
-                        self._log(step, metrics, mask, step_time)
+                        self._log(step, metrics, mask, step_time,
+                                  rows=int(np.prod(
+                                      batch_np["tokens"].shape[:-1])))
 
                 if ckpt and t.ckpt_every and (step + 1) % t.ckpt_every == 0:
                     with tracing.span(tracing.CKPT):
@@ -634,9 +648,9 @@ class CodedTrainer:
                 "final_step": end}
 
     def _log(self, step: int, metrics: dict, mask: np.ndarray,
-             step_time) -> None:
+             step_time, rows: int) -> None:
         """Append the step's history record: one device read of its
-        scalars."""
+        scalars.  ``rows``: the batch rows the step computed."""
         # read the LIVE config: controller actions may have replaced
         # self.tcfg since the loop started
         live = self.tcfg
@@ -655,6 +669,7 @@ class CodedTrainer:
                    DEC.err(self.code.G[:, mask])) / self.code.k,
                "n_workers": self.assignment.n,
                "s": self.code.s,
+               "rows": rows,
                "decoder": live.decoder}
         if step_time is not None:
             rec["step_time"] = float(step_time)

@@ -607,9 +607,12 @@ def test_adaptive_recode_metrics_match_fused_fp64():
 
 def test_ragged_workers_metrics_match_fused_8_devices():
     """n=7 workers on 8 devices (one padding lane): the dist trainer's
-    loss AND mean_ce equal the fused trainer's — padding rows are masked
-    out of the CE and the padded_n/n rescale undoes the row-count
-    dilution."""
+    loss equals the fused trainer's, and its mean_ce equals the CE mean
+    over the physical layout (every worker's rows, bgc's padding slots
+    included) — padding lanes are masked out of the CE and the
+    padded_n/n rescale undoes the row-count dilution.  The fused trainer
+    runs on one device, so it folds its batch: its mean_ce is the mean
+    over the held examples, each once."""
     res = _run_subprocess(x64=False, body="""
         from repro.training import CodedTrainConfig, CodedTrainer
 
@@ -631,13 +634,36 @@ def test_ragged_workers_metrics_match_fused_8_devices():
         from repro.runtime.faults import FaultPlan
 
         model = ToyModel()
+
+        def fused_oracles(tr, steps):
+            # the toy's mean_ce, at each step's parameters, over the
+            # physical layout and over the held examples' first replicas
+            state = tr.init_state()
+            phys, held = [], []
+            for step in range(steps):
+                p = jax.tree_util.tree_map(np.array, state["params"])
+                state = tr.run(state, start_step=step, steps=1)["state"]
+                b = tr.pipeline.batch_for_step(step, tr.weight_log[-1])
+                u, first = np.unique(tr.assignment.unique_row_of_slot(1),
+                                     return_index=True)
+                first = first[u >= 0]
+                for out_, rows in ((phys, slice(None)), (held, first)):
+                    sub = {k: jnp.asarray(v[rows]) for k, v in b.items()}
+                    out_.append(float(model.loss_fn(p, sub)[1]["mean_ce"]))
+            return phys, held
+
         out = {}
         for mode in ("fused", "coded_allreduce"):
             tr = CodedTrainer(model, CodedTrainConfig(
                 code="bgc", n_workers=7, s=2, decoder="onestep",
                 rows_per_slot=1, seq_len=16, steps=2, seed=0, log_every=1,
                 dist_mode=mode))
-            hist = tr.run()["history"]
+            if mode == "fused":
+                phys, held = fused_oracles(tr, 2)
+                out["oracle"] = {"phys": phys, "held": held}
+            else:
+                tr.run()
+            hist = tr.history
             out[mode] = {"loss": [h["loss"] for h in hist],
                          "mean_ce": [h["mean_ce"] for h in hist]}
         # elastic re-code mid-run: 8 workers -> 7 at step 1 makes the
@@ -650,7 +676,12 @@ def test_ragged_workers_metrics_match_fused_8_devices():
                 dist_mode=mode),
                 fault_injector=FaultInjector(
                     [FaultPlan(step=1, workers=(7,))]))
-            hist = tr.run()["history"]
+            if mode == "fused":
+                phys, held = fused_oracles(tr, 3)
+                out["oracle_fault"] = {"phys": phys, "held": held}
+            else:
+                tr.run()
+            hist = tr.history
             out[mode + "_fault"] = {
                 "mean_ce": [h["mean_ce"] for h in hist],
                 "workers": [h["n_workers"] for h in hist]}
@@ -661,10 +692,15 @@ def test_ragged_workers_metrics_match_fused_8_devices():
     np.testing.assert_allclose(res["coded_allreduce"]["loss"],
                                res["fused"]["loss"], rtol=1e-5)
     np.testing.assert_allclose(res["coded_allreduce"]["mean_ce"],
-                               res["fused"]["mean_ce"], rtol=1e-5)
+                               res["oracle"]["phys"], rtol=1e-5)
+    np.testing.assert_allclose(res["fused"]["mean_ce"],
+                               res["oracle"]["held"], rtol=1e-5)
     assert res["coded_allreduce_fault"]["workers"] == [8, 7, 7]
+    assert res["fused_fault"]["workers"] == [8, 7, 7]
     np.testing.assert_allclose(res["coded_allreduce_fault"]["mean_ce"],
-                               res["fused_fault"]["mean_ce"], rtol=1e-5)
+                               res["oracle_fault"]["phys"], rtol=1e-5)
+    np.testing.assert_allclose(res["fused_fault"]["mean_ce"],
+                               res["oracle_fault"]["held"], rtol=1e-5)
 
 
 # ==========================================================================

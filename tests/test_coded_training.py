@@ -84,6 +84,105 @@ class TestFusedDecodeEquivalence:
                                        rtol=5e-4, atol=5e-6)
 
 
+def _flat_grad(model, params, batch_np):
+    batch = {k: jnp.asarray(v) for k, v in batch_np.items()}
+    loss, grads = jax.value_and_grad(
+        lambda p: model.loss_fn(p, batch)[0])(params)
+    return float(loss), jnp.concatenate(
+        [g.reshape(-1).astype(jnp.float32)
+         for g in jax.tree_util.tree_leaves(grads)])
+
+
+def _held_examples(asg, T):
+    ids = asg.task_ids
+    return np.unique(ids[ids >= 0]).size * T
+
+
+class TestFold:
+    """data.pipeline.unique_batch_for_step: the physical batch folded to
+    each held example once, weighted by its replicas' summed weight."""
+
+    @pytest.mark.parametrize("code,decoder", [
+        ("frc", "onestep"), ("bgc", "onestep"),
+        ("frc", "optimal"), ("bgc", "optimal"),
+    ])
+    def test_folded_batch_matches_physical_and_master_decode(self, code,
+                                                            decoder):
+        model = tiny_model()
+        tr = make_trainer(model, code=code, decoder=decoder, rows_per_slot=2,
+                          exact_decode_renorm=False)
+        params = model.init(jax.random.PRNGKey(0))
+        mask = np.ones(8, dtype=bool)
+        mask[[1, 5]] = False
+        explicit, w = explicit_master_decode_grads(model, params, tr, 0, mask)
+        phys_np = tr.pipeline.batch_for_step(0, w)
+        fold_np = tr.pipeline.unique_batch_for_step(0, w)
+        asg = tr.assignment
+        assert fold_np["tokens"].shape[0] == _held_examples(asg, 2)
+        assert fold_np["tokens"].shape[0] < phys_np["tokens"].shape[0]
+        # each held task once, in task order, weighted (G w)_i / (k T)
+        held = np.unique(asg.task_ids[asg.task_ids >= 0])
+        want_w = np.repeat((asg.G @ w)[held] / (asg.k * 2), 2)
+        np.testing.assert_allclose(fold_np["loss_weight"], want_w,
+                                   rtol=1e-12, atol=0)
+        loss_p, g_p = _flat_grad(model, params, phys_np)
+        loss_f, g_f = _flat_grad(model, params, fold_np)
+        np.testing.assert_allclose(loss_f, loss_p, rtol=5e-4, atol=5e-6)
+        np.testing.assert_allclose(np.asarray(g_f), np.asarray(g_p),
+                                   rtol=5e-4, atol=5e-6)
+        np.testing.assert_allclose(np.asarray(g_f), np.asarray(explicit),
+                                   rtol=5e-4, atol=5e-6)
+
+    def test_fold_of_uncoded_is_identity(self):
+        model = tiny_model()
+        tr = make_trainer(model, code="uncoded", s=1, rows_per_slot=2)
+        w = tr.decode_weights_for(np.ones(8, dtype=bool))
+        phys = tr.pipeline.batch_for_step(3, w)
+        fold = tr.pipeline.unique_batch_for_step(3, w)
+        assert set(fold) == set(phys)
+        for key in phys:
+            np.testing.assert_array_equal(fold[key], phys[key])
+
+    def test_history_rows_count_what_the_step_computed(self):
+        model = tiny_model()
+        fused = make_trainer(model, code="bgc", steps=2, rows_per_slot=2)
+        rows = [h["rows"] for h in fused.run()["history"]]
+        held = _held_examples(fused.assignment, 2)
+        assert held < fused.pipeline.physical_batch
+        assert rows == [held] * 2
+        dist = make_trainer(model, code="bgc", steps=2, rows_per_slot=2,
+                            dist_mode="coded_allreduce")
+        rows = [h["rows"] for h in dist.run()["history"]]
+        assert rows == [dist.pipeline.physical_batch] * 2
+
+    def test_set_s_recode_rebuilds_the_fold(self):
+        from repro.control.policy import Action
+
+        model = tiny_model()
+        tr = make_trainer(model, code="bgc", steps=1, rows_per_slot=2)
+        before = tr.pipeline.unique_batch_for_step(0, np.ones(8))
+        tr._apply_action(Action(kind="set_s", value=4))
+        asg = tr.assignment
+        assert asg.slots >= 4
+        w = tr.decode_weights_for(np.ones(8, dtype=bool))
+        fold = tr.pipeline.unique_batch_for_step(0, w)
+        held = np.unique(asg.task_ids[asg.task_ids >= 0])
+        assert fold["tokens"].shape[0] == held.size * 2 \
+            != before["tokens"].shape[0]
+        np.testing.assert_allclose(
+            fold["loss_weight"],
+            np.repeat((asg.G @ w)[held] / (asg.k * 2), 2), rtol=1e-12)
+        # the held rows are the physical rows of the new layout
+        phys = tr.pipeline.batch_for_step(0, w)
+        first = {}
+        for r, u in enumerate(asg.unique_row_of_slot(2)):
+            first.setdefault(int(u), r)
+        src = [first[u] for u in sorted(first) if u >= 0]
+        np.testing.assert_array_equal(fold["tokens"], phys["tokens"][src])
+        out = tr.run(steps=1)
+        assert out["history"][-1]["rows"] == held.size * 2
+
+
 class TestTrainerLoop:
     def test_loss_decreases_no_stragglers(self):
         model = tiny_model()
