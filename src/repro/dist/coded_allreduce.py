@@ -263,39 +263,17 @@ class CodedAllReduce:
         the same padded row count so the mean of per-device means is the
         global mean.
 
-        Additive regularizers beyond the per-row weighted sum (the MoE
-        load-balance aux: loss = wloss + c*aux with the aux a LOCAL
-        batch mean) would psum to c*D*aux_mean; when the aux dict
-        carries the bare weighted loss under ``"loss"`` (the repo's
-        loss_fn convention), the local objective is recomposed as
-        ``wloss + (loss - wloss) * mine / n_real`` where ``mine`` zeroes
-        the term on padding-only devices (whose rows are all zero
-        tokens — their router statistics are garbage) and ``n_real``
-        averages over the devices that hold real workers, so the psum'd
-        regularizer matches the fused path.  Exact no-op when
-        loss == wloss (dense models, the fp64 differential toys).
+        The objective must be a weighted sum of per-row terms (the
+        MoE aux loss included: ``moe.aux_terms`` weights each row's like
+        its CE), so its psum is the fused path's objective.
         """
         ax = self.axis_name
-        # devices holding at least one real worker participate in the
-        # additive-regularizer average; padding-only devices are masked.
-        # The flag rides in as a worker-sharded input rather than an
-        # axis_index lookup: partial-auto meshes can't lower PartitionId
-        real_dev = self.partition.lane_mask.any(axis=1)     # [D] host-side
-        n_real = max(int(real_dev.sum()), 1)
-        flag = jnp.asarray(real_dev.astype(np.float32))     # [D]
 
-        def local(params, dbatch, flag_d):
+        def local(params, dbatch):
             batch = jax.tree_util.tree_map(lambda x: x[0], dbatch)
             if has_aux:
-                def local_loss(p, b):
-                    loss, aux = loss_fn(p, b)
-                    base = aux.get("loss") if isinstance(aux, dict) else None
-                    if base is not None:   # de-scale additive regularizers
-                        loss = base + (loss - base) * flag_d[0] / n_real
-                    return loss, aux
-
                 (loss, aux), grads = jax.value_and_grad(
-                    local_loss, has_aux=True)(params, batch)
+                    loss_fn, has_aux=True)(params, batch)
             else:
                 loss, grads = jax.value_and_grad(loss_fn)(params, batch)
                 aux = ()
@@ -304,12 +282,7 @@ class CodedAllReduce:
             aux = jax.tree_util.tree_map(lambda v: jax.lax.psum(v, ax), aux)
             return (loss, aux), grads
 
-        inner = self._shard_map(local, in_specs=(P(), P(ax), P(ax)),
-                                out_specs=P())
-
-        def fn(params, dbatch):
-            return inner(params, dbatch, flag)
-
+        fn = self._shard_map(local, in_specs=(P(), P(ax)), out_specs=P())
         return jax.jit(fn) if jit else fn
 
     def batch_sharding(self) -> NamedSharding:

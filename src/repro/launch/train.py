@@ -14,6 +14,7 @@ at its published widths, cut in depth, on one v5e chip).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 
 from repro.configs import get_config, list_archs
@@ -91,6 +92,10 @@ def main(argv=None) -> int:
     print(f"[train] device: platform={info.platform} "
           f"kind={info.device_kind!r} count={info.device_count}")
     cfg = get_config(args.arch, smoke=args.smoke)
+    if cfg.moe is not None:
+        # coded training computes expert layers dropless (CodedTrainer)
+        cfg = dataclasses.replace(
+            cfg, moe=dataclasses.replace(cfg.moe, dispatch="dropless"))
     model = build_model(cfg)
     print(f"[train] {cfg.name}: {model.param_count() / 1e6:.1f}M params, "
           f"code={args.code} s={args.s} decoder={args.decoder} "

@@ -93,10 +93,13 @@ def cross_block_specs(cfg: ArchConfig, prefix_shape=()) -> dict:
 
 # ----------------------------- caches ----------------------------------------
 
-def init_attn_cache(cfg: ArchConfig, batch: int, length: int, dtype) -> dict:
+def init_attn_cache(cfg: ArchConfig, batch: int, length: int, dtype,
+                    window: Optional[int] = None) -> dict:
     """Static-shape KV cache; windowed layers use a ring buffer of size
-    min(window, length)."""
-    W = min(cfg.local_window, length) if cfg.local_window else length
+    min(window, length).  ``window``: the layer's own (default
+    ``cfg.local_window``)."""
+    window = cfg.local_window if window is None else window
+    W = min(window, length) if window else length
     kv = cfg.n_kv
     return {
         "k": jnp.zeros((batch, W, kv, cfg.d_head), dtype),
@@ -139,8 +142,10 @@ def attn_block_apply(
     cache: Optional[dict] = None,
     use_moe: bool = False,
     seq_mask: Optional[jax.Array] = None,  # [B, S] bool: True = real token
+    yarn=None,                       # the layer's YaRN settings, if any
 ) -> Tuple[jax.Array, Optional[dict], jax.Array]:
-    """Pre-norm residual block. Returns (y, new_cache, aux_loss).
+    """Pre-norm residual block. Returns (y, new_cache, aux): aux a scalar
+    0, or with ``use_moe`` the expert layer's ``moe.MoEStats``.
 
     With ``seq_mask`` (masked ragged prefill) or 2-D ``positions``
     (decode after one), pad/invalid keys carry negative positions: they
@@ -150,7 +155,7 @@ def attn_block_apply(
     """
     with jax.named_scope(tracing.ATTENTION):
         x, new_cache = _attn_residual(params, x, cfg, positions, causal,
-                                      window, cache, seq_mask)
+                                      window, cache, seq_mask, yarn)
     with jax.named_scope(tracing.MLP):
         h2 = norm(x, params["ln2"], cfg.norm, io=cfg.norm_io)
         aux = jnp.zeros((), jnp.float32)
@@ -164,16 +169,16 @@ def attn_block_apply(
 
 def _attn_residual(params: dict, x: jax.Array, cfg: ArchConfig,
                    positions: jax.Array, causal: bool, window: int,
-                   cache: Optional[dict], seq_mask: Optional[jax.Array]
-                   ) -> Tuple[jax.Array, Optional[dict]]:
+                   cache: Optional[dict], seq_mask: Optional[jax.Array],
+                   yarn=None) -> Tuple[jax.Array, Optional[dict]]:
     """x + attention(ln1(x)) and the updated cache (attn_block_apply)."""
     B, S, _ = x.shape
     ragged = positions.ndim == 2
     h = norm(x, params["ln1"], cfg.norm, io=cfg.norm_io)
     q, k, v = _project_qkv(params["attn"], h, cfg)
     if cfg.rope_theta > 0:
-        q = rope(q, positions, cfg.rope_theta)
-        k = rope(k, positions, cfg.rope_theta)
+        q = rope(q, positions, cfg.rope_theta, yarn)
+        k = rope(k, positions, cfg.rope_theta, yarn)
     q = constrain(q, "batch", None, "act_heads", None)
     k = constrain(k, "batch", None, "act_kv", None)
 

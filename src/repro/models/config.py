@@ -10,7 +10,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
-__all__ = ["MoEConfig", "ArchConfig", "reduce_for_smoke"]
+__all__ = ["MoEConfig", "YarnConfig", "ArchConfig", "reduce_for_smoke"]
 
 
 def _round_up(x: int, m: int) -> int:
@@ -34,15 +34,54 @@ class MoEConfig:
     # 'grouped' : per-sequence dispatch (vmapped over batch) — sort,
     #             gather and scatter stay local to the data shard; the
     #             Sec-Perf optimization (EXPERIMENTS.md).
+    # 'dropless': every held expert runs on every token, gated (no
+    #             capacity), so a row's output and aux loss depend on
+    #             that row alone; the only dispatch CodedTrainer takes
+    #             (docs/architecture.md §2.1).  'global' and 'grouped'
+    #             serve the non-coded callers (ROADMAP 1.6).
     dispatch: str = "global"
     # Pad expert STORAGE to this count with zero-routed dummy experts so
     # the expert dim divides the 'model' axis (granite: 40 -> 48 on a
     # 16-way axis => clean EP; Sec-Perf iteration 2).  0 = no padding.
     pad_experts_to: int = 0
+    # (first, count): the experts stored on this device, one expert-
+    # parallel share; the router still scores all num_experts.  None =
+    # all of them.  A partial share needs dispatch='dropless'.
+    held: Optional[Tuple[int, int]] = None
+
+    def __post_init__(self):
+        if self.held is not None:
+            first, count = map(int, self.held)
+            object.__setattr__(self, "held", (first, count))
+            if not (0 <= first and count > 0
+                    and first + count <= self.num_experts):
+                raise ValueError(f"held {self.held} outside the "
+                                 f"{self.num_experts} experts")
+            if self.dispatch != "dropless":
+                raise ValueError("a share of the experts (held=) needs "
+                                 "dispatch='dropless'")
+        if self.dispatch not in ("global", "grouped", "dropless"):
+            raise ValueError(f"dispatch {self.dispatch!r}")
 
     @property
     def e_padded(self) -> int:
         return max(self.pad_experts_to, self.num_experts)
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        """(first, count) of the experts stored here."""
+        return self.held if self.held is not None else (0, self.e_padded)
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rotary scaling (the ``rope_type: yarn`` settings of a Hugging
+    Face config); ``layers.yarn_inv_freq`` computes the frequencies."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None   # None: 0.1 ln(factor) + 1
 
 
 @dataclasses.dataclass(frozen=True)
@@ -66,6 +105,12 @@ class ArchConfig:
     # --- block composition ---
     # repeating pattern of block kinds; "attn" | "rec" (RG-LRU) | "rwkv"
     block_pattern: Tuple[str, ...] = ("attn",)
+    # per position of block_pattern: the attention window (0 = global)
+    # and the rotary kind ("default" | "yarn"); () = local_window and
+    # plain RoPE in every layer
+    window_pattern: Tuple[int, ...] = ()
+    rope_pattern: Tuple[str, ...] = ()
+    yarn: Optional[YarnConfig] = None
     norm: str = "rmsnorm"        # rmsnorm | layernorm
     act: str = "swiglu"          # swiglu | geglu | gelu
     tie_embeddings: bool = False
@@ -99,6 +144,21 @@ class ArchConfig:
     schedule: str = "cosine"
 
     def __post_init__(self):
+        # JSON gives lists and dicts: the frozen, hashable forms
+        for key in ("block_pattern", "window_pattern", "rope_pattern"):
+            object.__setattr__(self, key, tuple(getattr(self, key)))
+        if isinstance(self.moe, dict):
+            object.__setattr__(self, "moe", MoEConfig(**self.moe))
+        if isinstance(self.yarn, dict):
+            object.__setattr__(self, "yarn", YarnConfig(**self.yarn))
+        for key in ("window_pattern", "rope_pattern"):
+            if getattr(self, key) and \
+                    len(getattr(self, key)) != len(self.block_pattern):
+                raise ValueError(f"{self.name}: {key} must match "
+                                 f"block_pattern's {len(self.block_pattern)}")
+        if "yarn" in self.rope_pattern and self.yarn is None:
+            raise ValueError(f"{self.name}: rope_pattern names yarn, but "
+                             f"no yarn settings are given")
         if self.d_head == 0:
             object.__setattr__(self, "d_head", self.d_model // max(self.n_heads, 1))
         if self.n_heads and self.n_kv and self.n_heads % self.n_kv:
@@ -123,6 +183,17 @@ class ArchConfig:
     def d_rnn(self) -> int:
         return self.rnn_width or self.d_model
 
+    def window_at(self, i: int) -> int:
+        """Attention window of pattern position ``i`` (0 = global)."""
+        return self.window_pattern[i] if self.window_pattern \
+            else self.local_window
+
+    def yarn_at(self, i: int) -> Optional[YarnConfig]:
+        """YaRN settings of pattern position ``i``; None = plain RoPE."""
+        if self.rope_pattern and self.rope_pattern[i] == "yarn":
+            return self.yarn
+        return None
+
     @property
     def pattern_counts(self) -> Tuple[int, int]:
         """(n_full_pattern_groups, n_remainder_layers)."""
@@ -142,7 +213,8 @@ class ArchConfig:
         per_moe = 0
         if self.moe is not None:
             m = self.moe
-            per_moe = (m.num_experts + m.num_shared) * (n_up + 1) * d * m.d_ff_expert \
+            held = m.held[1] if m.held is not None else m.num_experts
+            per_moe = (held + m.num_shared) * (n_up + 1) * d * m.d_ff_expert \
                 + d * m.num_experts
         per_rec = 0
         if "rec" in self.block_pattern:
@@ -195,7 +267,7 @@ def reduce_for_smoke(cfg: ArchConfig) -> ArchConfig:
         moe = dataclasses.replace(
             cfg.moe, num_experts=min(cfg.moe.num_experts, 4),
             top_k=min(cfg.moe.top_k, 2), d_ff_expert=32,
-            num_shared=min(cfg.moe.num_shared, 1),
+            num_shared=min(cfg.moe.num_shared, 1), held=None,
             # no capacity drops at smoke scale, so cached decode is exactly
             # parity with the full forward (drops are batch-dependent)
             capacity_factor=8.0)
@@ -213,6 +285,7 @@ def reduce_for_smoke(cfg: ArchConfig) -> ArchConfig:
         encoder_layers=2 if cfg.encoder_layers else 0,
         frontend_tokens=8 if cfg.frontend != "embed" else 0,
         local_window=min(cfg.local_window, 16) if cfg.local_window else 0,
+        window_pattern=tuple(min(w, 16) for w in cfg.window_pattern),
         rnn_width=64 if cfg.rnn_width else 0,
         param_dtype="float32",
         compute_dtype="float32",

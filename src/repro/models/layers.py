@@ -6,15 +6,17 @@ GSPMD can partition them; no framework dependency.
 
 from __future__ import annotations
 
-from typing import Optional
+import math
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..dist import constrain
 
 __all__ = [
-    "rmsnorm", "layernorm", "norm", "rope", "mlp",
+    "rmsnorm", "layernorm", "norm", "rope", "yarn_inv_freq", "mlp",
     "attention", "chunked_attention", "cross_entropy",
 ]
 
@@ -64,13 +66,48 @@ def norm(x: jax.Array, params: dict, kind: str, io: str = "f32") -> jax.Array:
 
 # ----------------------------- RoPE -----------------------------------------
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding.  x: [..., S, H, dh]; positions: [S] or [B, S]."""
+def yarn_inv_freq(dh: int, theta: float, yarn) -> Tuple[np.ndarray, float]:
+    """YaRN's rotary frequencies [dh/2] (float64) and the factor on cos
+    and sin, as Hugging Face ``_compute_yarn_parameters`` computes them:
+    the frequencies that turn fewer than ``beta_slow`` times over the
+    original context are divided by ``factor``, those that turn more
+    than ``beta_fast`` times are kept, and a linear ramp over the
+    dimensions in between mixes the two."""
+    half = dh // 2
+    base = theta ** (-np.arange(half, dtype=np.float64) / half)
+
+    def dim(rotations):      # the dimension that turns `rotations` times
+        return dh * math.log(yarn.original_max_position_embeddings
+                             / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    lo = max(math.floor(dim(yarn.beta_fast)), 0)
+    hi = min(math.ceil(dim(yarn.beta_slow)), dh - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = np.clip((np.arange(half) - lo) / (hi - lo), 0.0, 1.0)
+    inv = base / yarn.factor * ramp + base * (1.0 - ramp)
+    scale = yarn.attention_factor
+    if scale is None:
+        scale = 0.1 * math.log(yarn.factor) + 1.0
+    return inv, float(scale)
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         yarn=None) -> jax.Array:
+    """Rotary embedding.  x: [..., S, H, dh]; positions: [S] or [B, S].
+    ``yarn`` (a ``YarnConfig``): YaRN frequencies and scale."""
     dh = x.shape[-1]
     half = dh // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if yarn is None:
+        freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        inv, scale = yarn_inv_freq(dh, theta, yarn)
+        freq = jnp.asarray(inv, jnp.float32)
     ang = positions[..., None].astype(jnp.float32) * freq  # [..., S, half]
     sin, cos = jnp.sin(ang), jnp.cos(ang)
+    if yarn is not None:
+        sin, cos = sin * scale, cos * scale
     # broadcast over heads: [..., S, 1, half]
     sin, cos = sin[..., None, :], cos[..., None, :]
     x1, x2 = x[..., :half], x[..., half:]
@@ -196,7 +233,10 @@ def chunked_attention(
 
     Memory is O(q_block * kv_block) per step instead of O(Sq * Sk); this
     is the default train/prefill path for 4k-32k sequences and the
-    reference the Pallas kernel is checked against.
+    reference the Pallas kernel is checked against.  The backward pass
+    recomputes each block's scores (a checkpoint around each q block and
+    each kv step), so it too holds one block's at a time, not the
+    whole [Sq, Sk] probability matrix.
     """
     B, Sq, Kv, G, dh = qg.shape
     Sk = k.shape[1]
@@ -213,9 +253,11 @@ def chunked_attention(
 
     qs = jnp.moveaxis(qg.reshape(B, nq, q_block, Kv, G, dh), 1, 0)
 
+    @jax.checkpoint
     def q_step(q_i, qblk):  # qblk: [B, q_block, Kv, G, dh]
         qpos = q_offset + q_i * q_block + jnp.arange(q_block)
 
+        @jax.checkpoint
         def kv_step(carry, kv_i):
             m, l, acc = carry
             ks = jax.lax.dynamic_slice_in_dim(k, kv_i * kv_block, kv_block, 1)

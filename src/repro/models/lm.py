@@ -19,6 +19,7 @@ from .config import ArchConfig
 from .layers import cross_entropy, norm
 from .spec import ParamSpec
 from . import blocks as B
+from . import moe as moe_lib
 from . import rglru as R
 from . import rwkv6 as W
 
@@ -67,9 +68,12 @@ def lm_specs(cfg: ArchConfig) -> dict:
 
 # ------------------------- caches --------------------------------------------
 
-def _block_cache(kind: str, cfg: ArchConfig, batch: int, length: int, dtype):
+def _block_cache(kind: str, cfg: ArchConfig, batch: int, length: int, dtype,
+                 i: int):
+    """The cache of the block at pattern position ``i``."""
     if kind == "attn":
-        return B.init_attn_cache(cfg, batch, length, dtype)
+        return B.init_attn_cache(cfg, batch, length, dtype,
+                                 window=cfg.window_at(i))
     if kind == "rec":
         return R.init_rec_cache(cfg, batch, dtype)
     if kind == "rwkv":
@@ -85,17 +89,17 @@ def init_lm_cache(cfg: ArchConfig, batch: int, length: int,
         cache["stack"] = {
             f"p{i}": jax.tree_util.tree_map(
                 lambda x: jnp.broadcast_to(x, (groups,) + x.shape),
-                _block_cache(kind, cfg, batch, length, dtype))
+                _block_cache(kind, cfg, batch, length, dtype, i))
             for i, kind in enumerate(cfg.block_pattern)
         }
     elif groups > 0:
         cache["unrolled"] = {
-            f"l{g}_{i}": _block_cache(kind, cfg, batch, length, dtype)
+            f"l{g}_{i}": _block_cache(kind, cfg, batch, length, dtype, i)
             for g in range(groups)
             for i, kind in enumerate(cfg.block_pattern)
         }
     cache["rem"] = {
-        f"r{i}": _block_cache(kind, cfg, batch, length, dtype)
+        f"r{i}": _block_cache(kind, cfg, batch, length, dtype, i)
         for i, kind in enumerate(cfg.block_pattern[:rem])
     }
     return cache
@@ -144,12 +148,15 @@ def lm_cache_axes(cfg: ArchConfig) -> dict:
 # ------------------------- block dispatch -----------------------------------
 
 def _apply_block(kind: str, params, x, cfg: ArchConfig, positions, cache,
-                 seq_mask=None):
+                 seq_mask=None, i: int = 0):
+    """The block at pattern position ``i``: its window and RoPE are the
+    position's own."""
     if kind == "attn":
-        window = cfg.local_window
         y, c, aux = B.attn_block_apply(
-            params, x, cfg, positions=positions, causal=True, window=window,
-            cache=cache, use_moe=cfg.moe is not None, seq_mask=seq_mask)
+            params, x, cfg, positions=positions, causal=True,
+            window=cfg.window_at(i), cache=cache,
+            use_moe=cfg.moe is not None, seq_mask=seq_mask,
+            yarn=cfg.yarn_at(i))
         return y, c, aux
     if seq_mask is not None or positions.ndim == 2:
         # recurrent state would absorb pad tokens; the serving engine
@@ -210,25 +217,36 @@ def _run_blocks(params, cfg: ArchConfig, x, positions, caches=None,
                 for i, kind in enumerate(pat):
                     x, c_out, aux = _apply_block(
                         kind, p_slice[f"p{i}"], x, cfg, positions,
-                        c_slice[f"p{i}"], seq_mask)
+                        c_slice[f"p{i}"], seq_mask, i)
                     new_c[f"p{i}"] = c_out
-                    aux_g = aux_g + aux
+                    aux_g = moe_lib.add_stats(aux_g, aux)
                 return x, (aux_g, new_c)
 
             x, (auxs, ncs) = jax.lax.scan(group_body_dec, x,
                                           (stack_params, caches["stack"]))
             new_caches["stack"] = ncs
         else:
+            # a pattern of several blocks is rematerialized block by
+            # block, so the backward pass holds one block's intermediates
+            # at a time, not the whole group's
+            remat_block = len(pat) > 1
+
             def group_body(x, p_slice):
                 aux_g = jnp.zeros((), jnp.float32)
                 for i, kind in enumerate(pat):
-                    x, _, aux = _apply_block(kind, p_slice[f"p{i}"], x, cfg,
-                                             positions, None, seq_mask)
-                    aux_g = aux_g + aux
+                    def blk_fn(p, x, kind=kind, i=i):
+                        y, _, aux = _apply_block(kind, p, x, cfg, positions,
+                                                 None, seq_mask, i)
+                        return y, aux
+                    if remat_block:
+                        blk_fn = _remat(blk_fn, cfg)
+                    x, aux = blk_fn(p_slice[f"p{i}"], x)
+                    aux_g = moe_lib.add_stats(aux_g, aux)
                 return x, aux_g
 
-            x, auxs = jax.lax.scan(_remat(group_body, cfg), x, stack_params)
-        aux_total = aux_total + auxs.sum()
+            body = group_body if remat_block else _remat(group_body, cfg)
+            x, auxs = jax.lax.scan(body, x, stack_params)
+        aux_total = moe_lib.add_stats(aux_total, moe_lib.sum_stats(auxs))
     elif groups > 0:
         for g in range(groups):
             for i, kind in enumerate(pat):
@@ -238,26 +256,26 @@ def _run_blocks(params, cfg: ArchConfig, x, positions, caches=None,
                     x, c_out, aux = _apply_block(kind, p_blk, x, cfg,
                                                  positions,
                                                  caches["unrolled"][key],
-                                                 seq_mask)
+                                                 seq_mask, i)
                     new_caches.setdefault("unrolled", {})[key] = c_out
                 else:
-                    def blk_fn(p, x, kind=kind):
+                    def blk_fn(p, x, kind=kind, i=i):
                         y, _, aux = _apply_block(kind, p, x, cfg, positions,
-                                                 None, seq_mask)
+                                                 None, seq_mask, i)
                         return y, aux
                     fn = _remat(blk_fn, cfg) if cfg.remat != "none" else blk_fn
                     x, aux = fn(p_blk, x)
                     c_out = None
-                aux_total = aux_total + aux
+                aux_total = moe_lib.add_stats(aux_total, aux)
 
     for i, kind in enumerate(pat[:rem]):
         key = f"r{i}"
         c_in = caches["rem"][key] if decode else None
         x, c_out, aux = _apply_block(kind, params["rem"][key], x, cfg,
-                                     positions, c_in, seq_mask)
+                                     positions, c_in, seq_mask, i)
         if decode:
             new_caches.setdefault("rem", {})[key] = c_out
-        aux_total = aux_total + aux
+        aux_total = moe_lib.add_stats(aux_total, aux)
     if decode and "rem" not in new_caches:
         new_caches["rem"] = {}
     return x, aux_total, new_caches
@@ -272,7 +290,8 @@ def _logits(params, cfg: ArchConfig, x: jax.Array) -> jax.Array:
 
 
 def lm_forward(params, cfg: ArchConfig, batch: dict) -> Tuple[jax.Array, jax.Array]:
-    """Training/eval forward.  Returns (logits [B,S,Vp], aux_loss)."""
+    """Training/eval forward.  Returns (logits [B,S,Vp], aux): aux a
+    scalar 0, or with expert layers their ``moe.MoEStats`` summed."""
     cdt = jnp.dtype(cfg.compute_dtype)
     params = jax.tree_util.tree_map(
         lambda p: p.astype(cdt) if p.dtype == jnp.float32 and p.ndim >= 2 else p,
@@ -314,6 +333,11 @@ def lm_loss(params, cfg: ArchConfig, batch: dict) -> Tuple[jax.Array, dict]:
     batch: tokens [B,St] (+patches for vlm), labels [B,Sl],
            loss_weight [B] (the gradient-coding decode weights folded per
            row; uniform 1/B when uncoded), loss_mask [B,Sl] optional.
+
+    ``metrics["loss"]`` is the weighted CE.  With expert layers the aux
+    loss is per row and weighted like the CE, loss = sum_r w_r (ce_r +
+    0.01 aux_r), so each row's gradient is its own (the coding identity);
+    metrics carry the layers' counters (``moe.aux_terms``).
     """
     labels = batch["labels"]
     Sl = labels.shape[1]
@@ -335,12 +359,14 @@ def lm_loss(params, cfg: ArchConfig, batch: dict) -> Tuple[jax.Array, dict]:
     if mask is None:
         mask = jnp.ones_like(ce)
     row = (ce * mask).sum(-1) / jnp.maximum(mask.sum(-1), 1.0)
-    wloss = (row * batch["loss_weight"].astype(jnp.float32)).sum()
+    weight = batch["loss_weight"].astype(jnp.float32)
+    wloss = (row * weight).sum()
+    aux, aux_metrics = moe_lib.aux_terms(aux, weight)
     loss = wloss + 0.01 * aux
     metrics = {
         "loss": wloss,
-        "aux_loss": aux,
         "mean_ce": row.mean(),
+        **aux_metrics,
     }
     return loss, metrics
 

@@ -1,6 +1,8 @@
 """Mixture-of-Experts layer: top-k routing with sort-based capacity
-dispatch (gather -> grouped einsum -> scatter-add), plus a dense oracle
-used by tests.
+dispatch (gather -> grouped einsum -> scatter-add), a dropless dispatch
+over the experts held on this device, plus a dense oracle used by tests.
+Every dispatch reports ``MoEStats``, whose format this module alone
+knows (``add_stats``, ``sum_stats``, ``aux_terms``).
 
 Sharding: experts across the 'model' axis when E divides it (dbrx, EP);
 otherwise each expert's d_ff is tensor-parallel (granite, E=40).  The
@@ -9,16 +11,78 @@ dispatch is written with global gathers so GSPMD inserts the all-to-all.
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from .. import tracing
 from ..dist import constrain
 from .config import ArchConfig
 from .spec import ParamSpec
 
-__all__ = ["moe_specs", "moe_apply", "moe_apply_dense"]
+__all__ = ["MoEStats", "COUNTERS", "moe_specs", "moe_apply",
+           "moe_apply_dense", "add_stats", "sum_stats", "aux_terms"]
+
+
+class MoEStats(NamedTuple):
+    """What an expert layer reports besides its output."""
+    aux: jax.Array       # [B] each row's Switch loss, E sum_e f_e p_e over
+    #                      its own tokens and all E experts
+    routed: jax.Array    # assignments that landed on the held experts
+    max_load: jax.Array  # the largest held expert's count over the mean
+
+
+# the metrics ``aux_terms`` reports besides ``aux_loss``
+COUNTERS = ("moe_routed", "moe_max_load")
+
+
+def _stats(probs: jax.Array, onehot: jax.Array, B: int, cfg: ArchConfig
+           ) -> MoEStats:
+    """probs [T, E] and the top-k picks one-hot [T, K, E] -> MoEStats."""
+    m = cfg.moe
+    T, K, E = onehot.shape
+    S = T // B
+    first, H = m.held_range
+    picks = onehot.sum(1)                                    # [T, E]
+    f = picks.reshape(B, S, E).sum(1) / (S * K)              # [B, E]
+    p = probs.reshape(B, S, E).mean(1)
+    load = picks[:, first:first + H].sum(0)
+    return MoEStats(E * jnp.sum(f * p, axis=-1), load.sum(),
+                    load.max() / jnp.maximum(load.mean(), 1e-9))
+
+
+def add_stats(total, stats):
+    """The blocks' aux added over layers.  A block without an expert
+    layer gives a scalar 0; expert layers give ``MoEStats``, whose aux
+    and routed counts add and whose max_load is the largest layer's."""
+    if not isinstance(stats, MoEStats):
+        return total if isinstance(total, MoEStats) else total + stats
+    if not isinstance(total, MoEStats):
+        return stats
+    return MoEStats(total.aux + stats.aux, total.routed + stats.routed,
+                    jnp.maximum(total.max_load, stats.max_load))
+
+
+def sum_stats(stacked):
+    """``add_stats`` over the leading (layer-group) dim of a scan's aux."""
+    if not isinstance(stacked, MoEStats):
+        return stacked.sum()
+    return MoEStats(stacked.aux.sum(0), stacked.routed.sum(0),
+                    stacked.max_load.max(0))
+
+
+def aux_terms(aux, weight: jax.Array):
+    """(the aux loss a step adds, the metrics it reports).  Expert
+    layers' ``MoEStats``: each row's aux weighted by its decode weight
+    like its CE, sum_r w_r aux_r, so each row's gradient is its own;
+    metrics ``aux_loss`` (the rows' mean, like ``mean_ce``) and the
+    ``COUNTERS``.  A scalar (no expert layer): as it is."""
+    if not isinstance(aux, MoEStats):
+        return aux, {"aux_loss": aux}
+    return (aux.aux * weight).sum(), {"aux_loss": aux.aux.mean(),
+                                      "moe_routed": aux.routed,
+                                      "moe_max_load": aux.max_load}
 
 
 def _expert_axes(cfg: ArchConfig, prefix_len: int):
@@ -31,7 +95,9 @@ def _expert_axes(cfg: ArchConfig, prefix_len: int):
 def moe_specs(cfg: ArchConfig, prefix_shape=()) -> dict:
     m = cfg.moe
     d, f, E = cfg.d_model, m.d_ff_expert, m.num_experts
-    Ep = m.e_padded  # storage padded for EP divisibility (router stays E)
+    # storage: the held share, or all experts padded for EP divisibility;
+    # the router stays E wide
+    Ep = m.held_range[1]
     up_axes, down_axes = _expert_axes(cfg, len(prefix_shape))
     L = tuple("layers" for _ in prefix_shape)
     gated = cfg.act in ("swiglu", "geglu")
@@ -71,21 +137,24 @@ def _expert_ffn(tokens, wi, wg, wo, act):
 
 
 def moe_apply(params: dict, x: jax.Array, cfg: ArchConfig
-              ) -> Tuple[jax.Array, jax.Array]:
-    """Capacity-based top-k MoE.  x [B, S, d] -> (y [B, S, d], aux_loss).
-
-    aux_loss is the standard load-balancing loss (Switch): E * sum_e
-    f_e * p_e, where f_e = fraction of tokens routed to e, p_e = mean
-    router prob.
+              ) -> Tuple[jax.Array, MoEStats]:
+    """Top-k MoE.  x [B, S, d] -> (y [B, S, d], MoEStats): the stats'
+    aux is each row's load-balancing loss (Switch), E * sum_e f_e * p_e,
+    where f_e = the row's fraction of picks on e, p_e = its mean router
+    prob.
 
     dispatch='global'  sorts over all B*S tokens (baseline; replicated
     dispatch buffers under GSPMD).
     dispatch='grouped' vmaps the dispatch over the batch dim, so the
     sort/gather/scatter stay local to each data shard; capacity is per
     sequence (C = cf*S*K/E).  See EXPERIMENTS.md Sec-Perf / granite.
+    dispatch='dropless' drops nothing and computes only the held
+    experts (``_moe_apply_dropless``).
     """
     if cfg.moe.dispatch == "grouped":
         return _moe_apply_grouped(params, x, cfg)
+    if cfg.moe.dispatch == "dropless":
+        return _moe_apply_dropless(params, x, cfg)
     m = cfg.moe
     B, S, d = x.shape
     T = B * S
@@ -97,10 +166,8 @@ def moe_apply(params: dict, x: jax.Array, cfg: ArchConfig
     gate_vals, top_ids = jax.lax.top_k(probs, K)             # [T, K]
     gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
 
-    # ---- load-balancing aux loss ----
-    f = jnp.zeros((E,), jnp.float32).at[top_ids.reshape(-1)].add(1.0) / (T * K)
-    p = probs.mean(axis=0)
-    aux = E * jnp.sum(f * p)
+    stats = _stats(probs, jax.nn.one_hot(top_ids, E, dtype=jnp.float32),
+                   B, cfg)
 
     # ---- sort-based capacity dispatch ----
     Ep = m.e_padded          # dummy expert rows stay at the sentinel
@@ -138,11 +205,11 @@ def moe_apply(params: dict, x: jax.Array, cfg: ArchConfig
             params["shared_wo"], cfg.act).sum(0)
         out = out + sh
 
-    return out.reshape(B, S, d).astype(x.dtype), aux
+    return out.reshape(B, S, d).astype(x.dtype), stats
 
 
 def _moe_apply_grouped(params: dict, x: jax.Array, cfg: ArchConfig
-                       ) -> Tuple[jax.Array, jax.Array]:
+                       ) -> Tuple[jax.Array, MoEStats]:
     """Per-sequence capacity dispatch (vmapped over batch).
 
     Identical routing to the global path; only the capacity pool is per
@@ -164,9 +231,9 @@ def _moe_apply_grouped(params: dict, x: jax.Array, cfg: ArchConfig
     gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True),
                                         1e-9)
 
-    f = jnp.zeros((E,), jnp.float32).at[top_ids.reshape(-1)].add(1.0) \
-        / (B * S * K)
-    aux = E * jnp.sum(f * probs.mean(axis=(0, 1)))
+    stats = _stats(probs.reshape(B * S, E),
+                   jax.nn.one_hot(top_ids.reshape(B * S, K), E,
+                                  dtype=jnp.float32), B, cfg)
 
     def dispatch_one(xb, ids, gates):
         """xb [S, d]; ids/gates [S, K] -> (y [S, d])."""
@@ -206,11 +273,63 @@ def _moe_apply_grouped(params: dict, x: jax.Array, cfg: ArchConfig
             params["shared_wo"], cfg.act).sum(0)
         out = out + sh.reshape(B, S, d)
 
-    return out.astype(x.dtype), aux
+    return out.astype(x.dtype), stats
+
+
+def _held_experts(params: dict, flat: jax.Array, gates: jax.Array,
+                  cfg: ArchConfig) -> jax.Array:
+    """[T, d]: the held experts' part.  Every held expert runs on every
+    token, its output times the token's gate on it (``gates`` [T, H],
+    zero on the experts the token did not pick): T * H rows, at least
+    the T * min(K, H) any routing can send here, so nothing is dropped
+    and the cost is the same whatever the routing."""
+    with jax.named_scope(tracing.MOE_EXPERTS):
+        u = jnp.einsum("td,edf->tef", flat, params["wi"])
+        g = jnp.einsum("td,edf->tef", flat, params["wg"]) \
+            if "wg" in params else None
+        h = (_act(g, u, cfg.act).astype(jnp.float32)
+             * gates[..., None]).astype(u.dtype)
+        return jnp.einsum("tef,efd->td", h, params["wo"])
+
+
+def _moe_apply_dropless(params: dict, x: jax.Array, cfg: ArchConfig
+                        ) -> Tuple[jax.Array, MoEStats]:
+    """Top-k routing over all experts; every assignment to an expert held
+    here (``MoEConfig.held``) is computed, none dropped, and the others
+    are left to the devices that hold them (``_held_experts``).  A row's
+    output and aux loss depend on that row alone."""
+    m = cfg.moe
+    B, S, d = x.shape
+    T = B * S
+    E, K = m.num_experts, m.top_k
+    first, H = m.held_range
+    with jax.named_scope(tracing.MOE_DISPATCH):
+        flat = x.reshape(T, d)
+        logits = jnp.einsum("td,de->te", flat,
+                            params["router"]).astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_ids = jax.lax.top_k(probs, K)[1]                 # [T, K]
+        # the gates read through the one-hot picks, so their gradient
+        # is elementwise, not a scatter into [T, E]
+        onehot = jax.nn.one_hot(top_ids, E, dtype=jnp.float32)  # [T, K, E]
+        gate_vals = jnp.sum(onehot * probs[:, None, :], axis=-1)
+        gate_vals = gate_vals / jnp.maximum(
+            gate_vals.sum(-1, keepdims=True), 1e-9)
+        gates = jnp.sum(onehot[..., first:first + H]
+                        * gate_vals[..., None], axis=1)      # [T, H]
+        stats = _stats(probs, onehot, B, cfg)
+
+    out = _held_experts(params, flat, gates, cfg)
+    if m.num_shared:
+        out = out + _expert_ffn(
+            jnp.broadcast_to(flat, (m.num_shared,) + flat.shape),
+            params["shared_wi"], params.get("shared_wg"),
+            params["shared_wo"], cfg.act).sum(0)
+    return out.reshape(B, S, d).astype(x.dtype), stats
 
 
 def moe_apply_dense(params: dict, x: jax.Array, cfg: ArchConfig
-                    ) -> Tuple[jax.Array, jax.Array]:
+                    ) -> Tuple[jax.Array, MoEStats]:
     """Oracle: run every expert on every token, weight by (renormalized)
     top-k gates.  O(E) compute — test-only."""
     m = cfg.moe
@@ -232,8 +351,8 @@ def moe_apply_dense(params: dict, x: jax.Array, cfg: ArchConfig
         params["wi"], params.get("wg"), params["wo"], cfg.act)  # [Ep, T, d]
     out = jnp.einsum("te,etd->td", dense_gates.astype(x.dtype), all_y)
 
-    f = jnp.zeros((E,), jnp.float32).at[top_ids.reshape(-1)].add(1.0) / (T * K)
-    aux = E * jnp.sum(f * probs.mean(axis=0))
+    stats = _stats(probs, jax.nn.one_hot(top_ids, E, dtype=jnp.float32),
+                   B, cfg)
 
     if m.num_shared:
         sh = _expert_ffn(
@@ -241,4 +360,4 @@ def moe_apply_dense(params: dict, x: jax.Array, cfg: ArchConfig
             params["shared_wi"], params.get("shared_wg"),
             params["shared_wo"], cfg.act).sum(0)
         out = out + sh
-    return out.reshape(B, S, d).astype(x.dtype), aux
+    return out.reshape(B, S, d).astype(x.dtype), stats

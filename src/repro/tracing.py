@@ -24,7 +24,7 @@ import jax
 
 __all__ = ["DECODE", "BATCH", "H2D", "DISPATCH", "LOG", "RECODE", "CKPT",
            "HOST_SPANS", "ATTENTION", "MLP", "HEAD_CE", "OPTIMIZER",
-           "SCOPES", "span"]
+           "SCOPES", "MOE_DISPATCH", "MOE_EXPERTS", "MOE_SCOPES", "span"]
 
 # host spans of CodedTrainer.run, one leaf each
 DECODE = "repro.train.decode"        # straggler mask + decode weights
@@ -42,6 +42,10 @@ MLP = "mlp"               # ln2 .. the MLP (or MoE) and residual add
 HEAD_CE = "head_ce"       # final norm, head and cross-entropy
 OPTIMIZER = "optimizer"   # AdamW, global-norm clipping included
 SCOPES = (ATTENTION, MLP, HEAD_CE, OPTIMIZER)
+# inside MLP, under dropless MoE dispatch (models/moe.py)
+MOE_DISPATCH = "moe_dispatch"  # router, top-k, gates and per-row aux
+MOE_EXPERTS = "moe_experts"    # the held experts' matmuls, gates applied
+MOE_SCOPES = (MOE_DISPATCH, MOE_EXPERTS)
 
 
 def span(name: str) -> jax.profiler.TraceAnnotation:

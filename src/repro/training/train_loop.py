@@ -77,6 +77,7 @@ from ..core.engine import DecodeEngine
 from ..data import CodedDataPipeline, PipelineConfig
 from ..dist import use_mesh
 from ..models import Model
+from ..models.moe import COUNTERS
 from ..optim import OptConfig, adamw_update, init_opt_state, make_schedule
 from ..runtime import FaultInjector, StragglerModel, NoStragglers
 from .. import tracing
@@ -116,12 +117,27 @@ class CodedTrainConfig:
     #   set_decoder (docs/architecture.md §10).
 
 
+def _check_dropless(model: Model) -> None:
+    """Capacity dispatch drops tokens by the whole batch's routing, so a
+    row's gradient would depend on the other rows, which the coding
+    identity (each task's gradient its own) and the single-device fold
+    forbid: expert layers must be on dropless dispatch.  A model with no
+    ``cfg.moe`` (dense, or a test's toy) passes."""
+    m = getattr(getattr(model, "cfg", None), "moe", None)
+    if m is not None and m.dispatch != "dropless":
+        raise ValueError(
+            f"coded training needs MoEConfig(dispatch='dropless'), got "
+            f"{m.dispatch!r}: capacity dispatch makes each row's gradient "
+            f"depend on the whole batch")
+
+
 class CodedTrainer:
     def __init__(self, model: Model, tcfg: CodedTrainConfig,
                  straggler_model: Optional[StragglerModel] = None,
                  fault_injector: Optional[FaultInjector] = None,
                  mesh=None, trace=None, sync_policy=None,
                  controller=None, churn=None, recovery: str = "elastic"):
+        _check_dropless(model)
         self.model = model
         self.tcfg = tcfg
         self.straggler = straggler_model or NoStragglers()
@@ -359,7 +375,7 @@ class CodedTrainer:
                 (loss, metrics), grads = vg(params, batch)
                 # psum sums scalar aux over devices: means divide back
                 metrics = dict(metrics)
-                for key in ("mean_ce", "aux_loss"):
+                for key in ("mean_ce", "aux_loss", "moe_max_load"):
                     if key in metrics:
                         metrics[key] = metrics[key] / D
                 if "mean_ce" in metrics:
@@ -654,12 +670,13 @@ class CodedTrainer:
         # read the LIVE config: controller actions may have replaced
         # self.tcfg since the loop started
         live = self.tcfg
-        loss, mean_ce, grad_norm = jax.device_get(
-            (metrics["loss"], metrics["mean_ce"], metrics["grad_norm"]))
+        got = jax.device_get({k: metrics[k] for k in
+                              ("loss", "mean_ce", "grad_norm") + COUNTERS
+                              if k in metrics})
         rec = {"step": step,
-               "loss": float(loss),
-               "mean_ce": float(mean_ce),
-               "grad_norm": float(grad_norm),
+               "loss": float(got["loss"]),
+               "mean_ce": float(got["mean_ce"]),
+               "grad_norm": float(got["grad_norm"]),
                "stragglers": int((~mask).sum()),
                "decode_err": float(
                    DEC.err1(self.code.G[:, mask],
@@ -671,6 +688,9 @@ class CodedTrainer:
                "s": self.code.s,
                "rows": rows,
                "decoder": live.decoder}
+        for key in COUNTERS:
+            if key in got:
+                rec[key] = float(got[key])
         if step_time is not None:
             rec["step_time"] = float(step_time)
             rec["sim_time"] = float(self.sim_time)

@@ -788,8 +788,10 @@ def test_dist_trainer_8_devices_subprocess():
         # padding-only device): the dist loss's load-balance regularizer
         # must stay O(1), not O(D), and the padding device's garbage
         # router statistics must not contribute
-        moe = build_model(CFG.get_config("granite-moe-3b-a800m",
-                                         smoke=True))
+        import dataclasses
+        mcfg = CFG.get_config("granite-moe-3b-a800m", smoke=True)
+        moe = build_model(dataclasses.replace(mcfg, moe=dataclasses.replace(
+            mcfg.moe, dispatch="dropless")))
         mtr = CodedTrainer(moe, CodedTrainConfig(
             code="bgc", n_workers=7, s=2, decoder="onestep",
             rows_per_slot=1, seq_len=16, steps=1, seed=0,

@@ -145,7 +145,8 @@ class TestMoE:
         y_ref, aux_ref = moe_lib.moe_apply_dense(params, x, cfg)
         np.testing.assert_allclose(np.asarray(y_fast), np.asarray(y_ref),
                                    rtol=1e-4, atol=1e-5)
-        np.testing.assert_allclose(float(aux_fast), float(aux_ref), rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(aux_fast.aux),
+                                   np.asarray(aux_ref.aux), rtol=1e-5)
 
     def test_shared_experts(self):
         cfg = self._cfg(shared=1)
@@ -176,4 +177,6 @@ class TestMoE:
         params["router"] = jnp.zeros_like(params["router"])  # uniform
         x = jnp.asarray(RNG(10).normal(size=(4, 16, 16)), jnp.float32)
         _, aux = moe_lib.moe_apply(params, x, cfg)
-        assert 0.9 <= float(aux) <= 1.1
+        assert aux.aux.shape == (4,)
+        assert np.all((0.9 <= np.asarray(aux.aux))
+                      & (np.asarray(aux.aux) <= 1.1))
