@@ -6,6 +6,7 @@ GSPMD can partition them; no framework dependency.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional, Tuple
 
@@ -17,10 +18,12 @@ from ..dist import constrain
 
 __all__ = [
     "rmsnorm", "layernorm", "norm", "rope", "yarn_inv_freq", "mlp",
-    "attention", "chunked_attention", "cross_entropy",
+    "attention", "chunked_attention", "live_kv_blocks", "cross_entropy",
 ]
 
 _NEG_INF = -1e30
+# chunked attention's default tiles (``attention``'s q_block and kv_block)
+Q_BLOCK, KV_BLOCK = 512, 1024
 
 
 # ----------------------------- norms ---------------------------------------
@@ -180,8 +183,8 @@ def attention(
                                         # positions (ring caches)
     kv_valid: Optional[jax.Array] = None,  # [Sk] or [B, Sk] bool validity
     impl: str = "xla_naive",
-    q_block: int = 512,
-    kv_block: int = 1024,
+    q_block: int = Q_BLOCK,
+    kv_block: int = KV_BLOCK,
 ) -> jax.Array:
     """Grouped-query attention; returns [B, Sq, H, dh]."""
     B, Sq, H, dh = q.shape
@@ -217,6 +220,93 @@ def attention(
     return out.reshape(B, Sq, H, dh)
 
 
+def _kv_block_live(q_lo, q_hi, kv_lo, kv_block: int, Sk: int, causal: bool,
+                   window: int):
+    """Whether any query at absolute positions [q_lo, q_hi] attends to any
+    key of the kv block starting at ``kv_lo`` (``_mask_bias``'s mask).
+
+    A block is dead when it is padding only (``kv_lo >= Sk``), wholly in
+    the causal future, or wholly before every query's window.  Works on
+    Python ints and on traced scalars alike.
+    """
+    live = kv_lo < Sk
+    if causal:
+        live = live & (kv_lo <= q_hi)
+    if window > 0:
+        live = live & (kv_lo + kv_block - 1 > q_lo - window)
+    return live
+
+
+@functools.lru_cache(maxsize=None)
+def live_kv_blocks(Sq: int, Sk: int, q_block: int, kv_block: int,
+                   causal: bool, window: int) -> int:
+    """The (q block, kv block) pairs ``chunked_attention`` computes at
+    ``q_offset`` 0: those with a live (query, key) pair; it skips the
+    others."""
+    nq, nk = -(-Sq // q_block), -(-Sk // kv_block)
+    return sum(
+        bool(_kv_block_live(i * q_block, min((i + 1) * q_block, Sq) - 1,
+                            j * kv_block, kv_block, Sk, causal, window))
+        for i in range(nq) for j in range(nk))
+
+
+def _kv_block_body(opts, carry, qblk, ks, vs, qpos, kpos):
+    """One online-softmax update of (m, l, acc) by one kv block."""
+    scale, softcap, causal, window, Sk = opts
+    m, l, acc = carry
+    s = jnp.einsum("bqngd,bknd->bngqk", qblk, ks,
+                   preferred_element_type=jnp.float32) * scale
+    if softcap > 0.0:
+        s = jnp.tanh(s / softcap) * softcap
+    s = s + _mask_bias(qpos, kpos, causal, window, kpos < Sk)
+    m_new = jnp.maximum(m, s.max(axis=-1))
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new[..., None])
+    l_new = l * alpha + p.sum(axis=-1)
+    acc_new = acc * alpha[..., None] + jnp.einsum(
+        "bngqk,bknd->bngqd", p.astype(vs.dtype), vs,
+        preferred_element_type=jnp.float32)
+    return m_new, l_new, acc_new
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _kv_block_update(opts, live, carry, qblk, ks, vs, qpos, kpos):
+    """``_kv_block_body`` on a live block; a dead block leaves the carry as
+    it is, since what it would add is exp(-1e30 - m) = 0.
+
+    The custom VJP puts the same ``cond`` around the backward pass, whose
+    residuals are the block's inputs alone: differentiating the ``cond``
+    itself would make the dead branch write zeros for every score-sized
+    residual of the live one.
+    """
+    return jax.lax.cond(live, functools.partial(_kv_block_body, opts),
+                        lambda c, *_: c, carry, qblk, ks, vs, qpos, kpos)
+
+
+def _kv_block_update_fwd(opts, live, carry, qblk, ks, vs, qpos, kpos):
+    out = _kv_block_update(opts, live, carry, qblk, ks, vs, qpos, kpos)
+    return out, (live, carry, qblk, ks, vs, qpos, kpos)
+
+
+def _kv_block_update_bwd(opts, res, ct):
+    live, carry, qblk, ks, vs, qpos, kpos = res
+
+    def grads(ct):
+        _, vjp = jax.vjp(lambda *a: _kv_block_body(opts, *a, qpos, kpos),
+                         carry, qblk, ks, vs)
+        return vjp(ct)
+
+    def passthrough(ct):
+        return (ct, jnp.zeros_like(qblk), jnp.zeros_like(ks),
+                jnp.zeros_like(vs))
+
+    d = jax.lax.cond(live, grads, passthrough, ct)
+    return (None,) + d + (None, None)
+
+
+_kv_block_update.defvjp(_kv_block_update_fwd, _kv_block_update_bwd)
+
+
 def chunked_attention(
     qg: jax.Array,                # [B, Sq, Kv, G, dh]
     k: jax.Array,                 # [B, Sk, Kv, dh]
@@ -236,11 +326,14 @@ def chunked_attention(
     reference the Pallas kernel is checked against.  The backward pass
     recomputes each block's scores (a checkpoint around each q block and
     each kv step), so it too holds one block's at a time, not the
-    whole [Sq, Sk] probability matrix.
+    whole [Sq, Sk] probability matrix.  A kv block with no live (query,
+    key) pair for the q block (``_kv_block_live``) is skipped, forward
+    and backward: causal and sliding-window layers compute only the
+    blocks their mask reaches (``live_kv_blocks`` counts them).
     """
     B, Sq, Kv, G, dh = qg.shape
     Sk = k.shape[1]
-    scale = dh ** -0.5
+    opts = (dh ** -0.5, softcap, causal, window, Sk)
     nq = -(-Sq // q_block)
     pad_q = nq * q_block - Sq
     if pad_q:
@@ -255,28 +348,20 @@ def chunked_attention(
 
     @jax.checkpoint
     def q_step(q_i, qblk):  # qblk: [B, q_block, Kv, G, dh]
-        qpos = q_offset + q_i * q_block + jnp.arange(q_block)
+        q_lo = q_offset + q_i * q_block
+        q_hi = q_offset + jnp.minimum((q_i + 1) * q_block, Sq) - 1
+        qpos = q_lo + jnp.arange(q_block)
 
         @jax.checkpoint
         def kv_step(carry, kv_i):
-            m, l, acc = carry
-            ks = jax.lax.dynamic_slice_in_dim(k, kv_i * kv_block, kv_block, 1)
-            vs = jax.lax.dynamic_slice_in_dim(v, kv_i * kv_block, kv_block, 1)
-            s = jnp.einsum("bqngd,bknd->bngqk", qblk, ks,
-                           preferred_element_type=jnp.float32) * scale
-            if softcap > 0.0:
-                s = jnp.tanh(s / softcap) * softcap
-            kpos = kv_i * kv_block + jnp.arange(kv_block)
-            kvalid = kpos < Sk
-            s = s + _mask_bias(qpos, kpos, causal, window, kvalid)
-            m_new = jnp.maximum(m, s.max(axis=-1))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(s - m_new[..., None])
-            l_new = l * alpha + p.sum(axis=-1)
-            acc_new = acc * alpha[..., None] + jnp.einsum(
-                "bngqk,bknd->bngqd", p.astype(vs.dtype), vs,
-                preferred_element_type=jnp.float32)
-            return (m_new, l_new, acc_new), None
+            kv_lo = kv_i * kv_block
+            live = _kv_block_live(q_lo, q_hi, kv_lo, kv_block, Sk, causal,
+                                  window)
+            ks = jax.lax.dynamic_slice_in_dim(k, kv_lo, kv_block, 1)
+            vs = jax.lax.dynamic_slice_in_dim(v, kv_lo, kv_block, 1)
+            carry = _kv_block_update(opts, live, carry, qblk, ks, vs, qpos,
+                                     kv_lo + jnp.arange(kv_block))
+            return carry, None
 
         m0 = jnp.full((B, Kv, G, q_block), _NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, Kv, G, q_block), jnp.float32)

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from .. import tracing
 from ..dist import constrain
 from .config import ArchConfig
-from .layers import cross_entropy, norm
+from .layers import KV_BLOCK, Q_BLOCK, cross_entropy, live_kv_blocks, norm
 from .spec import ParamSpec
 from . import blocks as B
 from . import moe as moe_lib
@@ -24,7 +24,8 @@ from . import rglru as R
 from . import rwkv6 as W
 
 __all__ = ["lm_specs", "lm_forward", "lm_loss", "lm_prefill",
-           "lm_decode_step", "init_lm_cache", "lm_cache_axes"]
+           "lm_decode_step", "init_lm_cache", "lm_cache_axes",
+           "attn_live_blocks"]
 
 
 # ------------------------- specs ---------------------------------------------
@@ -146,6 +147,20 @@ def lm_cache_axes(cfg: ArchConfig) -> dict:
 
 
 # ------------------------- block dispatch -----------------------------------
+
+def attn_live_blocks(cfg: ArchConfig, seq_len: int) -> int:
+    """(q block, kv block) pairs that chunked attention computes in one
+    pass over ``seq_len`` positions from 0, summed over the trunk's
+    attention layers; 0 where attention does not take the chunked path."""
+    if cfg.attn_impl != "xla_chunked" or seq_len <= Q_BLOCK:
+        return 0
+    groups, rem = cfg.pattern_counts
+    kinds = list(enumerate(cfg.block_pattern)) * groups \
+        + list(enumerate(cfg.block_pattern[:rem]))
+    return sum(live_kv_blocks(seq_len, seq_len, Q_BLOCK, KV_BLOCK, True,
+                              cfg.window_at(i))
+               for i, kind in kinds if kind == "attn")
+
 
 def _apply_block(kind: str, params, x, cfg: ArchConfig, positions, cache,
                  seq_mask=None, i: int = 0):

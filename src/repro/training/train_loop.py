@@ -76,7 +76,8 @@ from ..core import registry as REG
 from ..core.engine import DecodeEngine
 from ..data import CodedDataPipeline, PipelineConfig
 from ..dist import use_mesh
-from ..models import Model
+from ..models import ArchConfig, Model
+from ..models.lm import attn_live_blocks
 from ..models.moe import COUNTERS
 from ..optim import OptConfig, adamw_update, init_opt_state, make_schedule
 from ..runtime import FaultInjector, StragglerModel, NoStragglers
@@ -649,7 +650,8 @@ class CodedTrainer:
                     with tracing.span(tracing.LOG):
                         self._log(step, metrics, mask, step_time,
                                   rows=int(np.prod(
-                                      batch_np["tokens"].shape[:-1])))
+                                      batch_np["tokens"].shape[:-1])),
+                                  seq_len=batch_np["tokens"].shape[-1])
 
                 if ckpt and t.ckpt_every and (step + 1) % t.ckpt_every == 0:
                     with tracing.span(tracing.CKPT):
@@ -664,9 +666,11 @@ class CodedTrainer:
                 "final_step": end}
 
     def _log(self, step: int, metrics: dict, mask: np.ndarray,
-             step_time, rows: int) -> None:
+             step_time, rows: int, seq_len: int) -> None:
         """Append the step's history record: one device read of its
-        scalars.  ``rows``: the batch rows the step computed."""
+        scalars.  ``rows``: the batch rows the step computed; for this
+        repository's architectures ``attn_blocks``, the (q, kv) block
+        pairs its chunked attention computed, from the shapes alone."""
         # read the LIVE config: controller actions may have replaced
         # self.tcfg since the loop started
         live = self.tcfg
@@ -688,6 +692,8 @@ class CodedTrainer:
                "s": self.code.s,
                "rows": rows,
                "decoder": live.decoder}
+        if isinstance(self.model.cfg, ArchConfig):
+            rec["attn_blocks"] = attn_live_blocks(self.model.cfg, seq_len)
         for key in COUNTERS:
             if key in got:
                 rec[key] = float(got[key])

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from repro.models import ArchConfig, MoEConfig
-from repro.models.layers import attention
+from repro.models.layers import _mask_bias, attention, live_kv_blocks
 from repro.models.rglru import rglru_scan_ref
 from repro.models.rwkv6 import wkv_chunked, wkv_scan_ref
 from repro.models import moe as moe_lib
@@ -98,13 +98,24 @@ class TestRGLRU:
 
 
 class TestChunkedAttention:
-    @pytest.mark.parametrize("causal,window", [(True, 0), (True, 7), (False, 0)])
-    def test_matches_naive(self, causal, window):
-        r = RNG(5)
-        B, S, H, Kv, dh = 2, 40, 4, 2, 8
-        q = jnp.asarray(r.normal(size=(B, S, H, dh)), jnp.float32)
-        k = jnp.asarray(r.normal(size=(B, S, Kv, dh)), jnp.float32)
-        v = jnp.asarray(r.normal(size=(B, S, Kv, dh)), jnp.float32)
+    # (S, causal, window) at q_block 16, kv_block 8: every causal or
+    # windowed case has kv blocks dead for a whole q block, which the skip
+    # drops; the S=40 cases keep the ids they had before S=64 joined them
+    GRID = [pytest.param(40, c, w, id=f"{c}-{w}")
+            for c, w in [(True, 0), (True, 7), (False, 0)]] + \
+        [pytest.param(64, c, w, id=f"S64-{c}-{w}")
+         for c, w in [(True, 0), (True, 7), (True, 20), (False, 0)]]
+
+    @staticmethod
+    def _qkv(seed, B, S, T, H, Kv, dh):
+        r = RNG(seed)
+        return (jnp.asarray(r.normal(size=(B, S, H, dh)), jnp.float32),
+                jnp.asarray(r.normal(size=(B, T, Kv, dh)), jnp.float32),
+                jnp.asarray(r.normal(size=(B, T, Kv, dh)), jnp.float32))
+
+    @pytest.mark.parametrize("S,causal,window", GRID)
+    def test_matches_naive(self, S, causal, window):
+        q, k, v = self._qkv(5, 2, S, S, 4, 2, 8)
         naive = attention(q, k, v, causal=causal, window=window,
                           impl="xla_naive")
         chunked = attention(q, k, v, causal=causal, window=window,
@@ -112,18 +123,75 @@ class TestChunkedAttention:
         np.testing.assert_allclose(np.asarray(chunked), np.asarray(naive),
                                    rtol=2e-5, atol=2e-5)
 
+    @pytest.mark.parametrize("S,causal,window", GRID)
+    def test_grad_matches_naive(self, S, causal, window):
+        """The skipped blocks' backward pass: gradients of q, k and v."""
+        q, k, v = self._qkv(7, 2, S, S, 4, 2, 8)
+        w = jnp.asarray(RNG(8).normal(size=q.shape), jnp.float32)
+
+        def loss(impl):
+            return lambda q, k, v: (attention(
+                q, k, v, causal=causal, window=window, impl=impl,
+                q_block=16, kv_block=8) * w).sum()
+
+        naive = jax.grad(loss("xla_naive"), argnums=(0, 1, 2))(q, k, v)
+        chunked = jax.grad(loss("xla_chunked"), argnums=(0, 1, 2))(q, k, v)
+        for got, want in zip(chunked, naive):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=1e-4, atol=1e-4)
+
     def test_softcap_and_offset(self):
-        r = RNG(6)
-        B, S, T, H, dh = 1, 24, 48, 2, 8
-        q = jnp.asarray(r.normal(size=(B, S, H, dh)), jnp.float32)
-        k = jnp.asarray(r.normal(size=(B, T, H, dh)), jnp.float32)
-        v = jnp.asarray(r.normal(size=(B, T, H, dh)), jnp.float32)
+        q, k, v = self._qkv(6, 1, 24, 48, 2, 2, 8)
         naive = attention(q, k, v, causal=True, softcap=20.0, q_offset=24,
                           impl="xla_naive")
         chunked = attention(q, k, v, causal=True, softcap=20.0, q_offset=24,
                             impl="xla_chunked", q_block=8, kv_block=16)
         np.testing.assert_allclose(np.asarray(chunked), np.asarray(naive),
                                    rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("window", [0, 7, 19])
+    def test_traced_offset(self, window):
+        """q_offset traced under jit (as training passes positions[0]),
+        Sq != Sk: the skip decides on device; value and gradient.  At
+        offset 17 a q block's last query is a kv block's first key, and
+        window 19 reaches exactly the last key of the block before a q
+        block's window: each edge keeps a block with one live entry."""
+        q, k, v = self._qkv(9, 1, 40, 64, 4, 2, 8)
+
+        def loss(impl):
+            def f(q, k, v, off):
+                return (attention(q, k, v, causal=True, window=window,
+                                  q_offset=off, impl=impl, q_block=16,
+                                  kv_block=8) ** 2).sum()
+            return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2)))
+
+        off = jnp.asarray(17, jnp.int32)
+        (vn, gn), (vc, gc) = (loss("xla_naive")(q, k, v, off),
+                              loss("xla_chunked")(q, k, v, off))
+        np.testing.assert_allclose(float(vc), float(vn), rtol=2e-5)
+        for got, want in zip(gc, gn):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("S,causal,window", GRID)
+    def test_live_kv_blocks_counts_unmasked_blocks(self, S, causal, window):
+        """live_kv_blocks equals a brute-force count of the (q, kv) block
+        pairs in which the naive mask leaves any entry."""
+        bias = np.asarray(_mask_bias(jnp.arange(S), jnp.arange(S), causal,
+                                     window))
+        nq, nk = -(-S // 16), -(-S // 8)
+        want = sum(bool((bias[i * 16:(i + 1) * 16,
+                              j * 8:(j + 1) * 8] == 0).any())
+                   for i in range(nq) for j in range(nk))
+        assert live_kv_blocks(S, S, 16, 8, causal, window) == want
+
+    def test_live_kv_blocks_mellum_period(self):
+        """Mellum at 4096: three 1024-window layers and one full causal
+        layer compute 62 of their 128 (q, kv) block pairs."""
+        live = [live_kv_blocks(4096, 4096, 512, 1024, True, w)
+                for w in (1024, 1024, 1024, 0)]
+        assert live == [14, 14, 14, 20]
+        assert sum(live) == 62
 
 
 @pytest.mark.slow  # dense-oracle comparisons: full MoE forwards

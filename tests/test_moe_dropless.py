@@ -229,6 +229,37 @@ def test_history_reads_the_expert_counters():
         assert 0 < rec["moe_routed"] <= 8 * 8 * 2 * 4
         assert rec["moe_max_load"] >= 1.0
         assert rec["rows"] == 8
+        assert rec["attn_blocks"] == 0   # 8 positions: no chunked path
+
+
+def test_attn_live_blocks_of_the_mellum_period():
+    """Three 1024-window layers and a full one at 4096 positions compute
+    62 of their 4 x 32 (q, kv) block pairs; each further period as many;
+    none where attention does not take the chunked path."""
+    cfg = moe_cfg(window_pattern=(1024, 1024, 1024, 0))
+    assert LM.attn_live_blocks(cfg, 4096) == 62
+    assert LM.attn_live_blocks(dataclasses.replace(cfg, n_layers=8),
+                               4096) == 124
+    assert LM.attn_live_blocks(cfg, 512) == 0
+    assert LM.attn_live_blocks(
+        dataclasses.replace(cfg, attn_impl="xla_naive"), 4096) == 0
+
+
+def test_history_counts_the_live_attention_blocks():
+    """Past 512 positions the trainer's record counts the block pairs its
+    chunked attention computed, from the batch's shapes."""
+    cfg = ArchConfig(
+        name="t", family="dense", n_layers=2, d_model=16, n_heads=2, n_kv=1,
+        d_head=8, d_ff=16, vocab=97, vocab_pad_to=32,
+        block_pattern=("attn",) * 2, window_pattern=(256, 0),
+        compute_dtype="float32", remat="none")
+    tr = CodedTrainer(build_model(cfg), CodedTrainConfig(
+        code="frc", n_workers=4, s=2, decoder="onestep", rows_per_slot=1,
+        seq_len=2048, seed=0, steps=1, log_every=1))
+    rec = tr.run()["history"][-1]
+    # q blocks of 512 over kv blocks of 1024: the 256-window layer 5 of 8,
+    # the full layer 6 of 8
+    assert rec["attn_blocks"] == LM.attn_live_blocks(cfg, 2048) == 11
 
 
 # --------------------------- windows and RoPE ------------------------------
